@@ -70,8 +70,7 @@ def train_demo():
     print("\n== Train a reduced granite-3-2b for 10 steps ==")
     from repro.launch.train import main as train_main
     train_main(["--arch", "granite-3-2b", "--smoke", "--steps", "10",
-                "--batch", "4", "--seq", "64",
-                "--ckpt-dir", "/tmp/repro_quickstart_ckpt"])
+                "--batch", "4", "--seq", "64"])
 
 
 if __name__ == "__main__":

@@ -95,6 +95,20 @@ class ModelConfig:
         return self.family in ("ssm", "hybrid") or (
             self.sliding_window > 0 and self.local_global_every >= 5)
 
+    def with_depth(self, n_layers: int) -> "ModelConfig":
+        """The same widths with only ``n_layers`` layers: a depth-only cut
+        that keeps every per-layer shape (and so the per-layer cost) as
+        published.  The cut must keep whole layer groups."""
+        group = max(self.attn_every, self.cross_attn_every,
+                    self.moe_every if self.n_experts else 1, 1)
+        if not 0 < n_layers <= self.n_layers or n_layers % group:
+            raise ValueError(
+                f"{self.name}: cannot cut {self.n_layers} layers to "
+                f"{n_layers} (a positive multiple of {group}, at most "
+                f"{self.n_layers})")
+        return dataclasses.replace(self, name=f"{self.name}-{n_layers}L",
+                                   n_layers=n_layers)
+
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         def cut(v, lo=1):

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
 from repro.core.overlap import sharedbus
 
 
@@ -36,7 +35,7 @@ def ag_matmul_body(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     Returns (B, T, F/n): the all-gathered-dim output, computed chunk-by-chunk
     while chunks circulate (overlap of ICI with MXU).
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, t, D = x.shape
     F = w.shape[1]
     out0 = jnp.zeros((n, B, t, F), x.dtype)
@@ -57,7 +56,7 @@ def matmul_rs_body(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     partial sums, hand the accumulator to the neighbor ("transmit shared
     row") while the next partial product is computed.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, T, f = x.shape
     D = w.shape[1]
@@ -75,14 +74,14 @@ def matmul_rs_body(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
             i < n - 1, lambda a: lax.ppermute(a, axis_name, perm),
             lambda a: a, acc)
 
-    acc = compat.pvary(jnp.zeros((B, t, D), x.dtype), (axis_name,))
+    acc = lax.pcast(jnp.zeros((B, t, D), x.dtype), axis_name, to="varying")
     return lax.fori_loop(0, n, body, acc)
 
 
 def ag_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
               axis_name: str = "model") -> jax.Array:
     """Y[B,T,F] = X[B,T,D] @ W[D,F], X seq-sharded / W col-sharded on axis."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(ag_matmul_body, axis_name=axis_name), mesh=mesh,
         in_specs=(P(None, axis_name, None), P(None, axis_name)),
         out_specs=P(None, None, axis_name))
@@ -92,7 +91,7 @@ def ag_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
 def matmul_rs(x: jax.Array, w: jax.Array, mesh: Mesh,
               axis_name: str = "model") -> jax.Array:
     """Y[B,T/n,D] = reduce_scatter_T(X[B,T,F] @ W[F,D]) with F sharded."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(matmul_rs_body, axis_name=axis_name), mesh=mesh,
         in_specs=(P(None, None, axis_name), P(axis_name, None)),
         out_specs=P(None, axis_name, None))

@@ -20,11 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import compat
-
 
 def ring_perm(axis_name: str, shift: int = 1) -> list[tuple[int, int]]:
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return [(i, (i + shift) % n) for i in range(n)]
 
 
@@ -38,12 +36,13 @@ def stream_ring(x: jax.Array, axis_name: str,
     ``consume`` of the current one — the Shared-PIM pipeline in Fig 4.
     Returns the final carry.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     shift = -1 if reverse else 1
     perm = ring_perm(axis_name, shift)
     # mark the carry as device-varying on the ring axis (shard_map vma typing)
-    init = jax.tree.map(lambda a: compat.pvary(a, (axis_name,)), init)
+    init = jax.tree.map(lambda a: lax.pcast(a, axis_name, to="varying"),
+                        init)
 
     def step(i, state):
         carry, buf = state
@@ -65,7 +64,7 @@ def bidirectional_stream(x: jax.Array, axis_name: str,
     """Split-ring variant: half the chunks flow clockwise, half counter-
     clockwise (doubling effective link bandwidth, like the paper's segmented
     BK-bus operating its segments in parallel)."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     fwd = ring_perm(axis_name, 1)
     bwd = ring_perm(axis_name, -1)

@@ -127,7 +127,7 @@ class BatchRunner:
     # --- placement-search layers (parallel + persistent) ------------------------
 
     def placement_oracle(self, cfg: SweepConfig, *, cache=None,
-                         n_workers: int | None = None, profile=None):
+                         n_workers: int = 1, profile=None):
         """A :class:`repro.search.PlacementOracle` over ``cfg``'s cell.
 
         Layered on this runner's dedup caches: the structural graph comes
@@ -150,7 +150,7 @@ class BatchRunner:
             n_workers=n_workers, profile=profile)
 
     def search_placement(self, cfg: SweepConfig, *, config=None,
-                         cache=None, n_workers: int | None = None,
+                         cache=None, n_workers: int = 1,
                          profile=None):
         """Run the cost-driven placement search on one sweep cell.
 

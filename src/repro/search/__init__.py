@@ -35,8 +35,7 @@ from repro.search.autotune import Autotuner, TunedChoice  # noqa: F401
 from repro.search.cache import OracleCache  # noqa: F401
 from repro.search.oracle import (SCALAR_ORACLE_CUTOVER,  # noqa: F401
                                  OracleStats, PlacementOracle,
-                                 geometry_key, placement_digest,
-                                 resolve_workers)
+                                 geometry_key, placement_digest)
 from repro.search.oracle import clear_caches  # noqa: F401
 from repro.search.place import (SearchConfig, SearchResult,  # noqa: F401
                                 search_pe_map)

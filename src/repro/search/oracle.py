@@ -26,9 +26,11 @@ full discrete-event engine result** (:func:`repro.core.engine
    event loop is chosen per graph size: the scalar loop for small oracle
    cells, the vectorized loop at scale — both bit-identical by the
    engine's core invariant, so the choice is pure speed.
-5. **process pool** — with ``n_workers > 1`` cache-missed candidates fan
-   out over a forked worker pool (workers inherit the base graph, model,
-   and warm move-cache by fork, sharing every structural memo).  Results
+5. **process pool** — only when asked for with ``n_workers > 1`` (the
+   default is 1: forking a process that holds an accelerator is unsafe),
+   cache-missed candidates fan out over a forked worker pool (workers
+   inherit the base graph, model, and warm move-cache by fork, sharing
+   every structural memo).  Results
    are merged in input order keyed by candidate digest, so a search is
    seed-reproducible regardless of worker count (asserted by
    ``tests/test_search.py``).
@@ -38,7 +40,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import os
 import weakref
 
 import numpy as np
@@ -83,16 +84,6 @@ def geometry_key(geom: DeviceGeometry) -> str:
             f"g{geom.banks_per_channel}b{geom.pes_per_bank}p")
 
 
-def resolve_workers(n_workers: int | None) -> int:
-    """``None`` -> the usable CPU count (affinity-aware), floored at 1."""
-    if n_workers is not None:
-        return max(1, int(n_workers))
-    try:
-        return max(1, len(os.sched_getaffinity(0)))
-    except (AttributeError, OSError):
-        return max(1, os.cpu_count() or 1)
-
-
 @dataclasses.dataclass
 class OracleStats:
     """Counters over one oracle's lifetime (mirrors the profile hooks)."""
@@ -116,7 +107,7 @@ class PlacementOracle:
                  geom: DeviceGeometry, *,
                  cache: OracleCache | None = None,
                  model: DeviceModel | None = None,
-                 n_workers: int | None = None,
+                 n_workers: int = 1,
                  profile=None, engine_kind: str | None = None):
         self.mode, self.geom = mode, geom
         self.base = ir.materialize(struct, mode)
@@ -132,7 +123,7 @@ class PlacementOracle:
         self.lb_model = LowerBoundModel(self.base, geom)
         self.cache = cache
         self.profile = profile
-        self.n_workers = resolve_workers(n_workers)
+        self.n_workers = max(1, int(n_workers))
         self.stats = OracleStats(n_workers=self.n_workers)
         from repro.obs.trace import graph_fingerprint
         self.key_prefix = (f"{graph_fingerprint(self.base)}/"

@@ -62,7 +62,7 @@ class SearchConfig:
     sa_temp: float = 0.02        # initial temperature, x incumbent makespan
     sa_decay: float = 0.8
     prune: bool = True           # admissible-surrogate pruning on/off
-    n_workers: int | None = None
+    n_workers: int = 1
     cache_path: str | None = None
 
     def describe(self) -> str:

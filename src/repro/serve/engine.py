@@ -30,8 +30,10 @@ class Engine:
         self.model = model
         self.params = params
         self.cfg = cfg
-        self._decode = jax.jit(model.decode_step)
-        self._prefill = jax.jit(model.prefill)
+        # the engine's two programs: (params, cache, tokens, media) ->
+        # (logits, cache)
+        self.decode = jax.jit(model.decode_step)
+        self.prefill = jax.jit(model.prefill)
 
     def generate(self, prompts: list[list[int]], max_new: int = 32,
                  media: np.ndarray | None = None) -> list[list[int]]:
@@ -53,8 +55,8 @@ class Engine:
              (jnp.zeros((B, self.model.cfg.n_media_tokens,
                          self.model.cfg.media_embed_dim), jnp.float32)
               if self.model.cfg.n_media_tokens else None))
-        logits, cache = self._prefill(self.params, cache,
-                                      jnp.asarray(toks), m)
+        logits, cache = self.prefill(self.params, cache,
+                                     jnp.asarray(toks), m)
         out = [list(p) for p in prompts]
         done = np.zeros(B, bool)
         key = jax.random.key(cfg.seed)
@@ -68,7 +70,7 @@ class Engine:
             if done.all() or int(cache["pos"]) >= cfg.max_len - 1:
                 break
             key = jax.random.fold_in(key, step)
-            logits, cache = self._decode(self.params, cache, cur, m)
+            logits, cache = self.decode(self.params, cache, cur, m)
             cur = self._sample(logits, key)
         return out
 

@@ -26,14 +26,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
-
 
 def _stage_body(stage_params, xs, f, axis_name: str, n_micro: int):
     """shard_map body: xs (n_micro, mb, ...) input microbatches (only stage
     0's copy is consumed).  Returns stacked outputs (only stage S-1's copy
     is meaningful)."""
-    n_stages = compat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     fwd = [(i, (i + 1) % n_stages) for i in range(n_stages)]
     # shard_map keeps the (now size-1) stage dim on the params; drop it
@@ -68,8 +66,8 @@ def _stage_body(stage_params, xs, f, axis_name: str, n_micro: int):
         buf = lax.ppermute(y, axis_name, fwd)
         return buf, outs
 
-    buf0 = compat.pvary(buf0, (axis_name,))
-    outs0 = compat.pvary(outs0, (axis_name,))
+    buf0 = lax.pcast(buf0, axis_name, to="varying")
+    outs0 = lax.pcast(outs0, axis_name, to="varying")
     _, outs = lax.fori_loop(0, ticks, tick, (buf0, outs0))
     return outs
 
@@ -88,13 +86,13 @@ def pipeline(f, stage_params, xs: jax.Array, mesh: Mesh,
 
     def reduce_out(stage_params, xs):
         outs = body(stage_params, xs)
-        n_stages = compat.axis_size(axis_name)
+        n_stages = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         # only the last stage holds real outputs; psum broadcasts them
         outs = jnp.where(me == n_stages - 1, outs, jnp.zeros_like(outs))
         return lax.psum(outs, axis_name)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         reduce_out, mesh=mesh,
         in_specs=(P(axis_name), P()), out_specs=P())
     return fn(stage_params, xs)

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
 from repro.core.overlap import compression
 from repro.models.model import Model
 from repro.optim import adamw
@@ -93,12 +92,10 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
         raise ValueError("compress_pod_grads requires a mesh with a 'pod' "
                          "axis")
 
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
-
     def podded(state, batch):
-        return compat.shard_map(
+        return jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P("pod")), out_specs=(P(), P()),
-            auto=auto, check_vma=False)(state, batch)
+            axis_names={"pod"}, check_vma=False)(state, batch)
 
     return podded

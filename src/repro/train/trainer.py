@@ -11,7 +11,9 @@ Production behaviours implemented (and covered by tests):
   triggers the pre-emption path; here it is fully testable logic.)
 * **failure retry**: transient step failures (injectable for tests) retry up
   to ``max_retries`` from the last good state — the state update is
-  transactional (functional state, no in-place mutation).
+  transactional (functional state, no in-place mutation).  A step that
+  donates its state and fails after consuming it leaves nothing to retry
+  from, so its error surfaces at once.
 * **elastic restart**: checkpoints restore onto a different mesh/device
   count via ``Checkpointer.restore(shardings=...)``.
 """
@@ -57,18 +59,26 @@ class StragglerWatchdog:
         return slow
 
 
+def _consumed(state) -> bool:
+    """True once a donating step has deleted the state's buffers."""
+    return any(isinstance(x, jax.Array) and x.is_deleted()
+               for x in jax.tree.leaves(state))
+
+
 class Trainer:
+    """``ckpt_dir=None`` trains without checkpoints (and never resumes)."""
+
     def __init__(self, step_fn: Callable, state, data_cfg: DataConfig,
-                 ckpt_dir: str, cfg: TrainerConfig = TrainerConfig(),
+                 ckpt_dir: str | None, cfg: TrainerConfig = TrainerConfig(),
                  fail_hook: Callable[[int], None] | None = None):
         self.step_fn = step_fn
         self.cfg = cfg
-        self.ckpt = Checkpointer(ckpt_dir)
+        self.ckpt = Checkpointer(ckpt_dir) if ckpt_dir is not None else None
         self.watchdog = StragglerWatchdog(cfg.straggler_factor)
         self.fail_hook = fail_hook          # test hook: raise to simulate
         self.metrics_log: list[dict] = []
 
-        latest = self.ckpt.latest_step()
+        latest = self.ckpt.latest_step() if self.ckpt else None
         if latest is not None:
             state, _ = self.ckpt.restore(state, latest)
             self.start_step = latest
@@ -95,7 +105,8 @@ class Trainer:
                             jax.tree.leaves(metrics)[0])
                         break
                     except Exception:
-                        if attempt == self.cfg.max_retries:
+                        if (attempt == self.cfg.max_retries
+                                or _consumed(self.state)):
                             raise
                 self.state = new_state
                 dt = time.perf_counter() - t0
@@ -105,11 +116,12 @@ class Trainer:
                         {"step": step + 1,
                          "loss": float(metrics["loss"]),
                          "sec_per_step": dt})
-                if (step + 1) % self.cfg.checkpoint_every == 0:
+                if self.ckpt and (step + 1) % self.cfg.checkpoint_every == 0:
                     self.ckpt.save_async(self.state, step + 1)
         finally:
             it.close()
-            self.ckpt.wait()
+            if self.ckpt:
+                self.ckpt.wait()
         return {"final_step": min(self.cfg.total_steps, step + 1),
                 "straggler_breaches": self.watchdog.breaches,
                 "metrics": self.metrics_log}

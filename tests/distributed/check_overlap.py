@@ -20,7 +20,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.core import compat  # noqa: E402
 from repro.core.overlap import collective_matmul as cm  # noqa: E402
 from repro.core.overlap import compression  # noqa: E402
 
@@ -67,9 +66,9 @@ def main():
         return compression.psum_compressed(gl, el, "data")
 
     mesh2 = jax.make_mesh((8,), ("data",))
-    fn = jax.jit(compat.shard_map(body, mesh=mesh2,
-                               in_specs=(P("data"), P("data")),
-                               out_specs=(P("data"), P("data"))))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh2,
+                              in_specs=(P("data"), P("data")),
+                              out_specs=(P("data"), P("data"))))
     mean, err = fn(g, e0)
     mean = np.asarray(mean)
     # every shard's mean equals the global mean (up to int8 quantization)

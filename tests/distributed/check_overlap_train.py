@@ -8,7 +8,7 @@ if jax.device_count() < 8:
     print("SKIP_NEED_MULTI_DEVICE")
     raise SystemExit(0)
 
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.configs import registry
 from repro.models import model as model_lib
 from repro.optim import adamw
@@ -16,7 +16,8 @@ from repro.sharding import partition
 from repro.sharding.context import use_mesh
 from repro.train import train_step as ts
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = dataclasses.replace(
     registry.get("glm4-9b").reduced(), d_model=64, n_heads=4, n_kv_heads=2,
     d_ff=128, overlap="shared_bus", constrain_activations=True)

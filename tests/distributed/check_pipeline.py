@@ -14,12 +14,13 @@ if jax.device_count() < 4:
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.train.pipeline import pipeline  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     n_stages, n_micro, mb, d = 4, 6, 2, 16
     w = jnp.asarray(rng.normal(size=(n_stages, d, d)) * 0.3, jnp.float32)

@@ -70,18 +70,11 @@ def test_input_specs_are_abstract():
     assert cache["k"].shape == (40, 128, 32768, 2, 128)
 
 
-def _abstract_mesh(shape, names):
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, names)          # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))  # jax 0.4.x
-
-
 def test_mesh_factory_shapes():
     """Mesh axis names/sizes via AbstractMesh (no 512 devices needed)."""
-    single = _abstract_mesh((16, 16), ("data", "model"))
-    multi = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    from jax.sharding import AbstractMesh
+    single = AbstractMesh((16, 16), ("data", "model"))
+    multi = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert dict(zip(single.axis_names, single.shape.values())) == {
         "data": 16, "model": 16}
     assert dict(zip(multi.axis_names, multi.shape.values())) == {

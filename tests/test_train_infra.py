@@ -179,6 +179,38 @@ class TestTrainerFaultTolerance:
         with pytest.raises(RuntimeError):
             tr.run()
 
+    def test_failure_after_donation_surfaces_first_error(self, tiny,
+                                                         tmp_path):
+        """A step that consumed its donated state is not retried on the
+        deleted buffers: the error it raised is the one that surfaces."""
+        cfg, model, opt, _, _, data = tiny
+        donating = jax.jit(ts.make_train_step(model, opt),
+                           donate_argnums=(0,))
+        calls = []
+
+        def step(state, batch):
+            calls.append(state)
+            donating(state, batch)
+            raise RuntimeError("device fault after donation")
+
+        state = ts.make_train_state(model, opt, jax.random.key(1))
+        tr = Trainer(step, state, data, str(tmp_path),
+                     TrainerConfig(total_steps=3, max_retries=2))
+        with pytest.raises(RuntimeError, match="device fault after donation"):
+            tr.run()
+        assert len(calls) == 1
+        assert jax.tree.leaves(state)[0].is_deleted()
+
+    def test_runs_without_checkpoint_dir(self, tiny):
+        cfg, model, opt, _, step, data = tiny
+        state = ts.make_train_state(model, opt, jax.random.key(1))
+        tr = Trainer(step, state, data, None,
+                     TrainerConfig(total_steps=3, checkpoint_every=1,
+                                   log_every=1))
+        out = tr.run()
+        assert tr.ckpt is None and tr.start_step == 0
+        assert [m["step"] for m in out["metrics"]] == [1, 2, 3]
+
     def test_resume_from_checkpoint(self, tiny, tmp_path):
         tr = self._mk(tiny, tmp_path, total=7)
         tr.run()
