@@ -1,0 +1,7 @@
+"""Share of the traced decode-cell window with no program on the chip."""
+
+from benchmarks.chip import readers
+
+
+def read(run):
+    return readers.idle_share(run)

@@ -1,0 +1,7 @@
+"""Least chip time of the prefill cell's required work over its window."""
+
+from benchmarks.chip import readers
+
+
+def read(run):
+    return readers.mfu(run)
