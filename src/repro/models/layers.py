@@ -18,6 +18,23 @@ Params = dict[str, Any]
 
 NEG_INF = -1e30
 
+# Layer-kind scopes (``jax.named_scope``), one name per kind of work.  They
+# change no instruction of a compiled program, only its ``op_name``
+# metadata, where a profiler trace's reduction finds each operation's kind
+# (the innermost of these names in its path; ``kv_cache`` nests inside
+# ``attention``).
+EMBED = "embed"
+ATTENTION = "attention"
+KV_CACHE = "kv_cache"
+MLP = "mlp"
+MOE = "moe"
+SSM = "ssm"
+UNEMBED = "unembed"
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+SCOPES = (EMBED, ATTENTION, KV_CACHE, MLP, MOE, SSM, UNEMBED, LOSS,
+          OPTIMIZER)
+
 
 # --- initialization helpers ------------------------------------------------------
 
@@ -149,6 +166,7 @@ def init_attn_params(key, d_model: int, spec: AttnSpec, dtype,
     return p
 
 
+@jax.named_scope(ATTENTION)
 def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
                rope_theta: float, norm_eps: float,
                positions: jax.Array,
@@ -188,10 +206,11 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     if kv_cache is not None:
         ck, cv = kv_cache
         pos = cache_len if cache_len is not None else 0
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, pos, 0, 0))
+        with jax.named_scope(KV_CACHE):
+            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                              (0, pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                              (0, pos, 0, 0))
         out = attention(q, ck, cv, spec, q_offset=pos, is_global=is_global,
                         kv_len=pos + x.shape[1])
         k_all, v_all = ck, cv
@@ -221,6 +240,7 @@ def _act(x: jax.Array, kind: str) -> jax.Array:
     return jax.nn.silu(x) if kind == "silu" else jax.nn.gelu(x)
 
 
+@jax.named_scope(MLP)
 def mlp_block(params: Params, x: jax.Array, act: str,
               overlap: bool = False, constrain_dp: bool = False
               ) -> jax.Array:

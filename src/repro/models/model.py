@@ -167,6 +167,7 @@ class Model:
 
     # ---------------- forward (train / prefill) ----------------
 
+    @jax.named_scope(layers.EMBED)
     def embed_inputs(self, params: Params, batch: dict) -> jax.Array:
         cfg = self.cfg
         x = params["embed"][batch["tokens"]]
@@ -199,6 +200,7 @@ class Model:
         logits = self._unembed(params, x)
         return logits
 
+    @jax.named_scope(layers.UNEMBED)
     def _unembed(self, params: Params, x: jax.Array) -> jax.Array:
         cfg = self.cfg
         w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
@@ -307,6 +309,7 @@ class Model:
         x, _ = self._scan(group, x, (blocks, params["cross_blocks"], flags))
         return x
 
+    @jax.named_scope(layers.SSM)
     def _ssm_layer(self, blk, x, state=None):
         cfg = self.cfg
         mixer = ssm.mamba1_block if cfg.mamba_version == 1 else \
@@ -362,15 +365,16 @@ class Model:
     def train_loss(self, params: Params, batch: dict) -> jax.Array:
         from repro.sharding.context import constrain
         logits = self.forward(params, batch)
-        # keep the vocab dimension sharded over 'model' through the loss —
-        # unsharded fp32 logits would dominate peak HBM at 256k vocab
-        logits = constrain(logits, ("pod", "data"), None, "model")
-        labels = batch["tokens"][:, 1:]
-        lg = logits[:, :-1].astype(jnp.float32)
-        lg = constrain(lg, ("pod", "data"), None, "model")
-        logz = jax.nn.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
+        with jax.named_scope(layers.LOSS):
+            # keep the vocab dimension sharded over 'model' through the loss —
+            # unsharded fp32 logits would dominate peak HBM at 256k vocab
+            logits = constrain(logits, ("pod", "data"), None, "model")
+            labels = batch["tokens"][:, 1:]
+            lg = logits[:, :-1].astype(jnp.float32)
+            lg = constrain(lg, ("pod", "data"), None, "model")
+            logz = jax.nn.logsumexp(lg, axis=-1)
+            gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
+            return jnp.mean(logz - gold)
 
     # ---------------- prefill ----------------
 
@@ -471,10 +475,11 @@ class Model:
                     ) -> tuple[jax.Array, dict]:
         """One serve step: tokens (B, 1) -> logits (B, 1, V), updated cache."""
         cfg = self.cfg
-        x = params["embed"][tokens]
-        if cfg.family == "audio" or (cfg.family == "dense"
-                                     and cfg.tie_embeddings):
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        with jax.named_scope(layers.EMBED):
+            x = params["embed"][tokens]
+            if cfg.family == "audio" or (cfg.family == "dense"
+                                         and cfg.tie_embeddings):
+                x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
         pos = cache["pos"]
         B = tokens.shape[0]
         positions = jnp.full((B, 1), pos, jnp.int32)
@@ -575,11 +580,12 @@ class Model:
             x, (nk, nv) = self._scan(self_layer, x, (grp, fl, kc, vc))
             h = layers.rms_norm(x, cross["ln"], cfg.norm_eps)
             # cross-attn against the cached media K/V (computed at prefill)
-            spec = self._attn_spec()
-            q = jnp.einsum("btd,dhk->bthk", h, cross["attn"]["wq"])
-            out = layers.attention(q, mk, mv, spec,
-                                   q_offset=mk.shape[1], is_global=True)
-            a = jnp.einsum("bthk,hkd->btd", out, cross["attn"]["wo"])
+            with jax.named_scope(layers.ATTENTION):
+                spec = self._attn_spec()
+                q = jnp.einsum("btd,dhk->bthk", h, cross["attn"]["wq"])
+                out = layers.attention(q, mk, mv, spec,
+                                       q_offset=mk.shape[1], is_global=True)
+                a = jnp.einsum("bthk,hkd->btd", out, cross["attn"]["wo"])
             x = x + jnp.tanh(cross["gate"]).astype(x.dtype) * a
             return x, (nk, nv)
 

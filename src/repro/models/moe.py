@@ -38,6 +38,7 @@ def init_moe_params(key, d_model: int, cfg, dtype) -> Params:
     return p
 
 
+@jax.named_scope(layers.MOE)
 def moe_block(params: Params, x: jax.Array, cfg, *,
               capacity_factor: float = 1.25) -> jax.Array:
     """x: (B, T, d) -> (B, T, d)."""

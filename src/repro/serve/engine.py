@@ -3,6 +3,19 @@
 A deliberately compact production shape: static max-batch slots, prompt
 prefill into per-slot cache regions, greedy/temperature sampling, and slot
 recycling when sequences finish — the serving counterpart of the trainer.
+
+What an operator can see: run ``generate`` under ``jax.profiler.trace`` and
+the host thread that calls it shows the spans ``engine.generate`` (the whole
+call), ``engine.admit`` (padding, token array, cache, media),
+``engine.prefill`` (prefill and the first sample) and, per decode
+iteration, ``engine.read_tokens`` (the per-slot token reads, EOS
+bookkeeping and stop test) and ``engine.decode`` (key, ``decode_step`` and
+sample).  Each carries ``batch=<n>``, the engine's count of ``generate``
+calls; the per-iteration ones also ``step=<k>``.  The counters in
+``Engine.metrics.snapshot()["counters"]`` are ``engine.host_reads``
+(device-to-host reads), ``engine.decode_steps`` (``decode_step`` calls) and
+``engine.decode_steps_kept`` (calls whose sampled token some request
+appended).
 """
 
 from __future__ import annotations
@@ -14,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import Model
+from repro.obs.metrics import MetricsRegistry
 
 
 @dataclasses.dataclass
@@ -34,6 +48,8 @@ class Engine:
         # (logits, cache)
         self.decode = jax.jit(model.decode_step)
         self.prefill = jax.jit(model.prefill)
+        self.metrics = MetricsRegistry()
+        self._batches = 0
 
     def generate(self, prompts: list[list[int]], max_new: int = 32,
                  media: np.ndarray | None = None) -> list[list[int]]:
@@ -46,32 +62,55 @@ class Engine:
         cfg = self.cfg
         B = len(prompts)
         assert B <= cfg.max_batch
-        plen = max(len(p) for p in prompts)
-        toks = np.zeros((B, plen), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, plen - len(p):] = p          # left-pad
-        cache = self.model.init_cache(B, cfg.max_len)
-        m = (jnp.asarray(media) if media is not None else
-             (jnp.zeros((B, self.model.cfg.n_media_tokens,
-                         self.model.cfg.media_embed_dim), jnp.float32)
-              if self.model.cfg.n_media_tokens else None))
-        logits, cache = self.prefill(self.params, cache,
-                                     jnp.asarray(toks), m)
-        out = [list(p) for p in prompts]
-        done = np.zeros(B, bool)
-        key = jax.random.key(cfg.seed)
-        cur = self._sample(logits, key)
-        for step in range(max_new):
-            for i in range(B):
-                if not done[i]:
-                    t = int(cur[i, 0])
-                    out[i].append(t)
-                    done[i] |= t == cfg.eos_token
-            if done.all() or int(cache["pos"]) >= cfg.max_len - 1:
-                break
-            key = jax.random.fold_in(key, step)
-            logits, cache = self.decode(self.params, cache, cur, m)
-            cur = self._sample(logits, key)
+        self._batches += 1
+        n = self._batches
+        reads = self.metrics.counter("engine.host_reads")
+        steps = self.metrics.counter("engine.decode_steps")
+        kept = self.metrics.counter("engine.decode_steps_kept")
+        with jax.profiler.TraceAnnotation("engine.generate", batch=n):
+            with jax.profiler.TraceAnnotation("engine.admit", batch=n):
+                plen = max(len(p) for p in prompts)
+                toks = np.zeros((B, plen), np.int32)
+                for i, p in enumerate(prompts):
+                    toks[i, plen - len(p):] = p          # left-pad
+                cache = self.model.init_cache(B, cfg.max_len)
+                m = (jnp.asarray(media) if media is not None else
+                     (jnp.zeros((B, self.model.cfg.n_media_tokens,
+                                 self.model.cfg.media_embed_dim),
+                                jnp.float32)
+                      if self.model.cfg.n_media_tokens else None))
+            with jax.profiler.TraceAnnotation("engine.prefill", batch=n):
+                logits, cache = self.prefill(self.params, cache,
+                                             jnp.asarray(toks), m)
+                key = jax.random.key(cfg.seed)
+                cur = self._sample(logits, key)
+            out = [list(p) for p in prompts]
+            done = np.zeros(B, bool)
+            for step in range(max_new):
+                with jax.profiler.TraceAnnotation("engine.read_tokens",
+                                                  batch=n, step=step):
+                    appended = 0
+                    for i in range(B):
+                        if not done[i]:
+                            t = int(cur[i, 0])
+                            out[i].append(t)
+                            done[i] |= t == cfg.eos_token
+                            appended += 1
+                    reads.inc(appended)
+                    stop = done.all()
+                    if not stop:
+                        reads.inc()
+                        stop = int(cache["pos"]) >= cfg.max_len - 1
+                if step and appended:
+                    kept.inc()
+                if stop:
+                    break
+                with jax.profiler.TraceAnnotation("engine.decode",
+                                                  batch=n, step=step):
+                    key = jax.random.fold_in(key, step)
+                    logits, cache = self.decode(self.params, cache, cur, m)
+                    cur = self._sample(logits, key)
+                steps.inc()
         return out
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:

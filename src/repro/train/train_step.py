@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.overlap import compression
+from repro.models import layers
 from repro.models.model import Model
 from repro.optim import adamw
 
@@ -80,8 +81,9 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
             grads, new_err = compression.tree_psum_compressed(
                 grads, state["grad_err"], "pod")
             new_state["grad_err"] = new_err
-        params, opt, metrics = adamw.apply_updates(
-            opt_cfg, state["params"], grads, state["opt"])
+        with jax.named_scope(layers.OPTIMIZER):
+            params, opt, metrics = adamw.apply_updates(
+                opt_cfg, state["params"], grads, state["opt"])
         new_state.update(params=params, opt=opt, step=state["step"] + 1)
         return new_state, {"loss": loss, **metrics}
 

@@ -16,11 +16,19 @@ Production behaviours implemented (and covered by tests):
   from, so its error surfaces at once.
 * **elastic restart**: checkpoints restore onto a different mesh/device
   count via ``Checkpointer.restore(shardings=...)``.
+
+What an operator can see: run ``Trainer.run`` under ``jax.profiler.trace``
+and its thread shows, per step and each with ``step=<k>``, the spans
+``trainer.data_wait`` (taking the batch from the prefetch queue, and once
+more after the last step), ``trainer.step`` (the step call through
+``block_until_ready``, retries included), ``trainer.log`` (reading the loss
+into the log) and ``trainer.checkpoint`` (starting an async save).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import statistics
 import time
 from typing import Callable
@@ -90,34 +98,46 @@ class Trainer:
     def run(self) -> dict:
         it = PrefetchIterator(self.corpus, start_step=self.start_step)
         try:
-            for step, batch in it:
+            for step in itertools.count(self.start_step):
+                # the queue yields consecutive steps from start_step;
+                # taking the batch after the last step too spares close()
+                # waiting out the prefetch thread's blocked put
+                with jax.profiler.TraceAnnotation("trainer.data_wait",
+                                                  step=step):
+                    _, batch = next(it)
                 if step >= self.cfg.total_steps:
                     break
                 t0 = time.perf_counter()
                 # retry THIS step from the last good state until the retry
                 # budget is exhausted (transient node failures)
-                for attempt in range(self.cfg.max_retries + 1):
-                    try:
-                        if self.fail_hook is not None:
-                            self.fail_hook(step)
-                        new_state, metrics = self.step_fn(self.state, batch)
-                        jax.block_until_ready(
-                            jax.tree.leaves(metrics)[0])
-                        break
-                    except Exception:
-                        if (attempt == self.cfg.max_retries
-                                or _consumed(self.state)):
-                            raise
+                with jax.profiler.TraceAnnotation("trainer.step", step=step):
+                    for attempt in range(self.cfg.max_retries + 1):
+                        try:
+                            if self.fail_hook is not None:
+                                self.fail_hook(step)
+                            new_state, metrics = self.step_fn(self.state,
+                                                              batch)
+                            jax.block_until_ready(
+                                jax.tree.leaves(metrics)[0])
+                            break
+                        except Exception:
+                            if (attempt == self.cfg.max_retries
+                                    or _consumed(self.state)):
+                                raise
                 self.state = new_state
                 dt = time.perf_counter() - t0
                 self.watchdog.observe(dt)
                 if (step + 1) % self.cfg.log_every == 0:
-                    self.metrics_log.append(
-                        {"step": step + 1,
-                         "loss": float(metrics["loss"]),
-                         "sec_per_step": dt})
+                    with jax.profiler.TraceAnnotation("trainer.log",
+                                                      step=step):
+                        self.metrics_log.append(
+                            {"step": step + 1,
+                             "loss": float(metrics["loss"]),
+                             "sec_per_step": dt})
                 if self.ckpt and (step + 1) % self.cfg.checkpoint_every == 0:
-                    self.ckpt.save_async(self.state, step + 1)
+                    with jax.profiler.TraceAnnotation("trainer.checkpoint",
+                                                      step=step):
+                        self.ckpt.save_async(self.state, step + 1)
         finally:
             it.close()
             if self.ckpt:

@@ -7,6 +7,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ import pytest
 from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from benchmarks.chip import layer_trace
 from repro.configs import registry
 from repro.models import model as model_lib
 from repro.optim import adamw
@@ -72,21 +75,37 @@ def granite(one_chip):
     return model, _placed(params, one_chip)
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill"])
-def test_serving_program_compiles_for_one_chip(granite, one_chip, program):
+@pytest.fixture(scope="module")
+def serving(granite, one_chip):
     """The serve launcher's two programs at its default batch, prompt and
-    cache length (4 requests, 256-token prompts, 1024-token cache)."""
+    cache length (4 requests, 256-token prompts, 1024-token cache),
+    compiled once for the tests that read them."""
     model, params = granite
     batch, prompt_len, max_len = 4, 256, 1024
     cache = _placed(jax.eval_shape(lambda: model.init_cache(batch, max_len)),
                     one_chip)
-    n_tokens = 1 if program == "decode_step" else prompt_len
-    tokens = jax.ShapeDtypeStruct((batch, n_tokens), jnp.int32,
-                                  sharding=one_chip)
-    compiled = jax.jit(getattr(model, program)).lower(
-        params, cache, tokens, None).compile()
+    out = {}
+    for program, n_tokens in (("decode_step", 1), ("prefill", prompt_len)):
+        tokens = jax.ShapeDtypeStruct((batch, n_tokens), jnp.int32,
+                                      sharding=one_chip)
+        out[program] = jax.jit(getattr(model, program)).lower(
+            params, cache, tokens, None).compile()
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_serving_program_compiles_for_one_chip(serving, program):
     # the bf16 parameters alone are 4.7 GiB
-    assert _fits(compiled) > 4.5 * 2**30
+    assert _fits(serving[program]) > 4.5 * 2**30
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_serving_program_keeps_layer_scopes(serving, program):
+    """The chip's compiler keeps each layer kind in the ``op_name`` of the
+    instructions it emits, where a profiler trace shows them."""
+    names = re.findall(r'op_name="([^"]*)"', serving[program].as_text())
+    kinds = {layer_trace.kind_of(n) for n in names}
+    assert {"embed", "attention", "kv_cache", "mlp", "unembed"} <= kinds
 
 
 def test_train_step_compiles_for_one_chip(topo):

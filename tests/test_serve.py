@@ -68,3 +68,57 @@ def test_eos_stops_slot():
                           eos_token=first_tok)
     out = eng.generate([prompt], max_new=8)[0]
     assert out == prompt + [first_tok]
+
+
+def _no_eos_engine():
+    # an end token the model cannot produce: every request runs max_new
+    return _engine("granite-3-2b", eos_token=-1)
+
+
+@pytest.mark.parametrize("batch,max_new", [(1, 2), (4, 5)])
+def test_generate_emits_engine_spans(batch, max_new):
+    """One admit and one prefill per call, then one read_tokens and one
+    decode per decode iteration, each tagged with the call and step."""
+    from _profile import spans
+
+    cfg, eng = _no_eos_engine()
+    prompts = [[2 + i, 3, 4] for i in range(batch)]
+    eng.generate(prompts, max_new=1)          # compile outside the trace
+    found = spans(lambda: eng.generate(prompts, max_new=max_new),
+                  "engine.")
+    names = [n for n, _ in found]
+    assert names == (["engine.generate", "engine.admit", "engine.prefill"]
+                     + ["engine.read_tokens", "engine.decode"] * max_new)
+    assert all(stats["batch"] == 2 for _, stats in found)
+    steps = [stats["step"] for n, stats in found
+             if n == "engine.read_tokens"]
+    assert steps == list(range(max_new))
+
+
+@pytest.mark.parametrize("batch,max_new", [(1, 2), (4, 5), (3, 1)])
+def test_engine_counters(batch, max_new):
+    """B token reads plus the position read per decode iteration, one
+    decode_step per iteration, and the last one's token never kept."""
+    cfg, eng = _no_eos_engine()
+    prompts = [[2 + i, 3, 4] for i in range(batch)]
+    for calls in (1, 2):
+        eng.generate(prompts, max_new=max_new)
+        c = eng.metrics.snapshot()["counters"]
+        assert c["engine.decode_steps"] == calls * max_new
+        assert c["engine.host_reads"] == calls * max_new * (batch + 1)
+        assert c["engine.decode_steps_kept"] == calls * (max_new - 1)
+
+
+def test_engine_counters_stop_at_eos():
+    """A request that ended is no longer read; a batch whose requests all
+    ended stops before another decode_step."""
+    cfg, eng = _engine("granite-3-2b", temperature=0.0)
+    prompt = [5, 9, 4]
+    first = eng.generate([prompt], max_new=4)[0][len(prompt)]
+    eng = Engine(eng.model, eng.params,
+                 ServeConfig(max_batch=4, max_len=96, eos_token=first))
+    assert eng.generate([prompt], max_new=4) == [prompt + [first]]
+    c = eng.metrics.snapshot()["counters"]
+    assert c["engine.host_reads"] == 1
+    assert c["engine.decode_steps"] == 0
+    assert c["engine.decode_steps_kept"] == 0

@@ -233,3 +233,32 @@ class TestTrainerFaultTolerance:
                                  shardings=shardings)
         leaf = jax.tree.leaves(restored)[0]
         assert leaf.sharding.mesh.shape == {"data": 1}
+
+
+@pytest.mark.parametrize("start,total", [(0, 3), (2, 4)])
+def test_run_emits_one_data_wait_and_step_span_per_step(tiny, tmp_path,
+                                                        start, total):
+    """Per step: the batch taken from the queue, the step, the log line;
+    a save where a checkpoint falls due; and last the batch taken after
+    the last step, which ends the loop."""
+    from _profile import spans
+
+    cfg, model, opt, _, step, data = tiny
+    tr = Trainer(step, ts.make_train_state(model, opt, jax.random.key(1)),
+                 data, str(tmp_path),
+                 TrainerConfig(total_steps=start + 1, checkpoint_every=2,
+                               log_every=1))
+    tr.start_step = start
+    tr.run()                                  # compile outside the trace
+    tr.start_step, tr.cfg.total_steps = start, total
+    found = spans(tr.run, "trainer.")
+    per_step = []
+    for k in range(start, total):
+        per_step += ["trainer.data_wait", "trainer.step", "trainer.log"]
+        if (k + 1) % 2 == 0:
+            per_step.append("trainer.checkpoint")
+    assert [n for n, _ in found] == per_step + ["trainer.data_wait"]
+    assert [s["step"] for n, s in found if n == "trainer.step"] == list(
+        range(start, total))
+    assert [s["step"] for n, s in found if n == "trainer.data_wait"] == list(
+        range(start, total + 1))
