@@ -148,6 +148,35 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, Tq, H, Dh).astype(q.dtype)
 
 
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     spec: AttnSpec, *, pos: jax.Array | int,
+                     is_global: jax.Array | bool = True) -> jax.Array:
+    """Attention of one new token per sequence over its cache, read where
+    it lies.
+
+    q: (B, 1, H, Dh), the token at position ``pos``; k, v: (B, S, K, Dh),
+    the cache with that token's K/V written.  The same numbers as
+    :func:`attention` with ``q_offset=pos`` and ``kv_len=pos + 1`` (float32
+    scores, softmax and accumulation, the same masks and softcap), but the
+    two dots read the cache in its own layout: no block reshape or
+    transpose, so the compiler reads it in place.
+    """
+    B, _, H, Dh = q.shape
+    S, K = k.shape[1], k.shape[2]
+    qg = (q[:, 0].astype(jnp.float32) * Dh ** -0.5).reshape(B, K, H // K, Dh)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32))
+    s = _softcap(s, spec.softcap)
+    delta = jnp.asarray(pos) - jnp.arange(S)                         # (S,)
+    ok = delta >= 0
+    if spec.window > 0:
+        ok &= jnp.asarray(is_global) | (delta < spec.window)
+    s = jnp.where(ok, s, NEG_INF)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    out = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
+    out = out / p.sum(axis=-1)[..., None]
+    return out.reshape(B, 1, H, Dh).astype(q.dtype)
+
+
 def init_attn_params(key, d_model: int, spec: AttnSpec, dtype,
                      qk_norm: bool = False) -> Params:
     ks = jax.random.split(key, 4)
@@ -173,6 +202,7 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
                is_global: jax.Array | bool = True,
                kv_cache: tuple[jax.Array, jax.Array] | None = None,
                cache_len: jax.Array | None = None,
+               layer: jax.Array | int | None = None,
                xkv: jax.Array | None = None,
                use_rope: bool = True,
                constrain_dp: bool = False,
@@ -180,8 +210,10 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     """Projections + (cached) attention.  Returns (out, (k_all, v_all)).
 
     * training/prefill: ``kv_cache`` is None -> attends within x.
-    * decode: ``kv_cache`` holds (B, S, K, Dh); x is the new token(s); the
-      cache is updated at ``cache_len``.
+    * decode: ``kv_cache`` holds (B, S, K, Dh), or the stack of every
+      layer's, (L, B, S, K, Dh), of which this is ``layer``; x is the new
+      token(s); the cache is updated at ``cache_len`` and returned whole.
+      One new token reads it through :func:`decode_attention`.
     * cross-attention: ``xkv`` supplies the key/value source sequence.
     """
     src = x if xkv is None else xkv
@@ -206,13 +238,22 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     if kv_cache is not None:
         ck, cv = kv_cache
         pos = cache_len if cache_len is not None else 0
+        # the stacked cache is written and read at the layer's index: where
+        # the compiler sees a constant there, it updates the cache in place
+        # and feeds the layer's slice straight to the dots
+        kn, vn, at = k.astype(ck.dtype), v.astype(cv.dtype), ()
+        if layer is not None:
+            kn, vn, at = kn[None], vn[None], (layer,)
         with jax.named_scope(KV_CACHE):
-            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                              (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                              (0, pos, 0, 0))
-        out = attention(q, ck, cv, spec, q_offset=pos, is_global=is_global,
-                        kv_len=pos + x.shape[1])
+            ck = jax.lax.dynamic_update_slice(ck, kn, (*at, 0, pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, vn, (*at, 0, pos, 0, 0))
+        lk, lv = (ck, cv) if layer is None else (ck[layer], cv[layer])
+        if x.shape[1] == 1:
+            out = decode_attention(q, lk, lv, spec, pos=pos,
+                                   is_global=is_global)
+        else:
+            out = attention(q, lk, lv, spec, q_offset=pos,
+                            is_global=is_global, kv_len=pos + x.shape[1])
         k_all, v_all = ck, cv
     elif xkv is not None:
         # cross-attention: no causal mask — emulate by huge offset

@@ -11,8 +11,9 @@
 Layer stacks are scanned (``jax.lax.scan`` over stacked params) so the HLO
 stays compact for the 512-device dry-run; heterogeneous schedules (gemma
 local/global, zamba2 shared attention, llama-vision cross blocks) are
-expressed as scanned per-layer flags or group-structured scans — never as
-Python-unrolled towers.
+expressed as scanned per-layer flags or group-structured scans.  The one
+unrolled tower is the decoder stack's ``decode_step``: there each layer
+updates and reads the stacked KV cache in place, at a constant index.
 """
 
 from __future__ import annotations
@@ -220,7 +221,7 @@ class Model:
         return constrain(x, ("pod", "data"), None, None)
 
     def _decoder_layer(self, blk: Params, x, positions, is_global,
-                       kv_cache=None, cache_len=None):
+                       kv_cache=None, cache_len=None, layer=None):
         cfg = self.cfg
         spec = self._attn_spec()
         x = self._constrain_residual(x)
@@ -228,7 +229,7 @@ class Model:
         a, kv = layers.attn_block(
             blk["attn"], h, spec, rope_theta=cfg.rope_theta,
             norm_eps=cfg.norm_eps, positions=positions, is_global=is_global,
-            kv_cache=kv_cache, cache_len=cache_len,
+            kv_cache=kv_cache, cache_len=cache_len, layer=layer,
             use_rope=cfg.family != "audio",
             constrain_dp=cfg.constrain_internals)
         x = x + a
@@ -490,17 +491,19 @@ class Model:
                 x, cache = self._moe_grouped_pass(params, cache, x,
                                                   positions, pos)
             else:
-                def layer(x, inp):
-                    blk, is_global, kc, vc = inp
-                    x, (nk, nv) = self._decoder_layer(
-                        blk, x, positions, is_global, kv_cache=(kc, vc),
-                        cache_len=pos)
-                    return x, (nk, nv)
-
-                x, (nk, nv) = self._scan(
-                    layer, x,
-                    (params["blocks"], flags, cache["k"], cache["v"]))
-                cache = {**cache, "k": nk, "v": nv}
+                # unrolled: each layer writes its token into the stacked
+                # cache and reads its own slice at an index the compiler
+                # sees as a constant, both in place, where a scan would
+                # slice every layer's cache out as xs and stack the new
+                # ones as ys.  One jitted layer, traced once and called
+                # per layer, keeps tracing and lowering at one layer's cost
+                layer = jax.jit(self._decoder_layer)
+                kv = (cache["k"], cache["v"])
+                for i in range(cfg.n_layers):
+                    x, kv = layer(_take(params["blocks"], i), x, positions,
+                                  flags[i], kv_cache=kv, cache_len=pos,
+                                  layer=jnp.int32(i))
+                cache = {**cache, "k": kv[0], "v": kv[1]}
         elif cfg.family == "vlm":
             x, cache = self._decode_vlm(params, cache, x, positions, media)
         elif cfg.family == "ssm":

@@ -45,9 +45,11 @@ class Engine:
         self.params = params
         self.cfg = cfg
         # the engine's two programs: (params, cache, tokens, media) ->
-        # (logits, cache)
-        self.decode = jax.jit(model.decode_step)
-        self.prefill = jax.jit(model.prefill)
+        # (logits, cache).  Each takes the cache's buffers over (donated),
+        # so the new cache is written where the old one was: a caller
+        # passes a cache once and goes on with the one returned
+        self.decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        self.prefill = jax.jit(model.prefill, donate_argnums=(1,))
         self.metrics = MetricsRegistry()
         self._batches = 0
 

@@ -20,6 +20,7 @@ from benchmarks.chip import layer_trace
 from repro.configs import registry
 from repro.models import model as model_lib
 from repro.optim import adamw
+from repro.serve.engine import Engine, ServeConfig
 from repro.sharding import partition
 from repro.sharding.context import use_mesh
 from repro.train import train_step as ts
@@ -106,6 +107,74 @@ def test_serving_program_keeps_layer_scopes(serving, program):
     names = re.findall(r'op_name="([^"]*)"', serving[program].as_text())
     kinds = {layer_trace.kind_of(n) for n in names}
     assert {"embed", "attention", "kv_cache", "mlp", "unembed"} <= kinds
+
+
+@pytest.fixture(scope="module")
+def serve_decode(granite, one_chip):
+    """The engine's own decode program, which takes the cache over
+    (donated), at the serve-decode cell's shapes: 16 requests over a
+    3072-token cache.  Returns the compiled program and the cache."""
+    model, params = granite
+    batch, max_len = 16, 3072
+    engine = Engine(model, params, ServeConfig(max_batch=batch,
+                                               max_len=max_len))
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(batch, max_len)),
+                    one_chip)
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    return engine.decode.lower(params, cache, tokens, None).compile(), cache
+
+
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]"
+                          r"\S* ([\w\-]+)\(([^)]*)\)")
+
+
+def _top_level(text: str) -> list[tuple[str, int, str, list[str]]]:
+    """(opcode, output bytes, name, operand names) of every array-valued
+    instruction of an optimized HLO module outside fused computations:
+    the buffers the program writes to memory."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    out, computation = [], None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+            computation = head.group(1) if head else computation
+            continue
+        m = _INSTRUCTION.match(line)
+        if computation in fused or not m:
+            continue
+        bits = re.search(r"\d+", m.group(2))       # bf16, f32, s8; pred
+        size = max(1, int(bits.group()) // 8) if bits else 1
+        for d in filter(None, m.group(3).split(",")):
+            size *= int(d)
+        out.append((m.group(4), size, m.group(1),
+                    re.findall(r"%[\w.\-]+", m.group(5))))
+    return out
+
+
+def test_decode_step_updates_the_cache_in_place(serve_decode):
+    """The new cache is the donated one: the step writes each layer's new
+    token where the cache lies and reads each layer where it lies.  Its
+    only instructions with an output of a layer's cache or more are the
+    2 x 40 writes of one token's K and V into the stacked cache, in place;
+    no copy, slice or restack of a layer's cache is left (the scan over
+    the layers made about ten per layer)."""
+    compiled, cache = serve_decode
+    _fits(compiled)
+    k, v = cache["k"], cache["v"]
+    layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= (k.size + v.size) * k.dtype.itemsize)
+    ops = _top_level(compiled.as_text())
+    size = {name: n for _, n, name, _ in ops}
+    big = [(op, operands) for op, n, _, operands in ops
+           if n >= layer_bytes
+           and op not in ("parameter", "get-tuple-element", "bitcast")]
+    # a dynamic-update-slice reuses its operand's buffer and writes only
+    # its update
+    in_place = [op for op, operands in big if op == "dynamic-update-slice"
+                and size.get(operands[1], 0) < layer_bytes]
+    assert len(in_place) == 2 * 40
+    assert len(big) - len(in_place) == 0, big
 
 
 def test_train_step_compiles_for_one_chip(topo):

@@ -1,5 +1,7 @@
 """Serving engine integration tests across model families."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -9,8 +11,10 @@ from repro.models import model as model_lib
 from repro.serve.engine import Engine, ServeConfig
 
 
-def _engine(arch, **kw):
+def _engine(arch, dtype=None, **kw):
     cfg = registry.get(arch).reduced()
+    if dtype:
+        cfg = dataclasses.replace(cfg, dtype=dtype)
     model = model_lib.build(cfg)
     params = model.init(jax.random.key(0))
     return cfg, Engine(model, params, ServeConfig(max_batch=4, max_len=96,
@@ -41,20 +45,49 @@ def test_greedy_deterministic():
     assert a == b
 
 
-def test_greedy_matches_teacher_forcing():
-    """Engine decode must agree with argmax over the forward logits."""
-    cfg, eng = _engine("granite-3-2b", temperature=0.0)
+@pytest.mark.parametrize("arch,prompt_len,dtype", [
+    ("granite-3-2b", 5, None),
+    # local/global layers with an 8-token window and a logit softcap: a
+    # 12-token prompt puts early tokens outside the local layers' window.
+    # In float32: in bfloat16 two of its logits lie half a bfloat16 step
+    # apart, closer than the two programs' roundings agree
+    ("gemma2-9b", 12, "float32"),
+    # audio: media frames ahead of the tokens, no rotary embeddings
+    ("musicgen-medium", 5, None),
+])
+def test_greedy_matches_teacher_forcing(arch, prompt_len, dtype):
+    """Engine decode, over its donated cache, must agree with argmax over
+    the forward logits."""
+    cfg, eng = _engine(arch, dtype=dtype, temperature=0.0, eos_token=-1)
     rng = np.random.default_rng(2)
-    prompt = list(rng.integers(2, cfg.vocab_size, size=5))
-    out = eng.generate([prompt], max_new=4)[0]
+    prompt = list(rng.integers(2, cfg.vocab_size, size=prompt_len))
+    out = eng.generate([prompt], max_new=6)[0]
+    assert len(out) == prompt_len + 6
     model = eng.model
     import jax.numpy as jnp
     # teacher-force the generated sequence and check each next-token argmax
-    toks = jnp.asarray([out])
-    logits = model.forward(eng.params, {"tokens": toks})
+    batch = {"tokens": jnp.asarray([out])}
+    if cfg.n_media_tokens:
+        batch["media"] = jnp.zeros(
+            (1, cfg.n_media_tokens, cfg.media_embed_dim), jnp.float32)
+    logits = model.forward(eng.params, batch)
     for t in range(len(prompt) - 1, len(out) - 1):
         want = int(jnp.argmax(logits[0, t]))
         assert out[t + 1] == want, f"mismatch at position {t}"
+
+
+def test_chip_smoke_replay_chains_the_donated_cache():
+    """``chip_smoke.py``'s replay passes each returned cache on to the
+    next donated call; at CPU sizes it replays the served tokens and its
+    logits match the forward pass."""
+    import chip_smoke
+
+    cfg, eng = _no_eos_engine()
+    prompts = [[2 + i, 3, 4, 5, 6] for i in range(3)]
+    outs = eng.generate(prompts, max_new=6)
+    gap, gap_prefill = chip_smoke.cached_logit_gap(eng, prompts, outs)
+    assert gap <= chip_smoke.LOGIT_TOL
+    assert gap_prefill <= chip_smoke.LOGIT_TOL
 
 
 def test_eos_stops_slot():
