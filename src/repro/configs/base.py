@@ -27,6 +27,10 @@ class ModelConfig:
 
     # --- attention variants ---
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"       # rope | nope (none at all)
+    attention_multiplier: float = 0.0      # score scale; 0 -> 1/sqrt(Dh)
+    attn_kv_block: int = 512               # keys per step of the block scan
+    #   (prefill / training): its scores are (B, T, heads, block) float32
     sliding_window: int = 0                # >0: local-attention window size
     local_global_every: int = 0            # N: every Nth layer is global
     attn_logit_softcap: float = 0.0        # gemma2-style tanh capping
@@ -46,10 +50,16 @@ class ModelConfig:
     ssm_expand: int = 2
     mamba_version: int = 1                 # 1: falcon-mamba, 2: zamba2
     ssm_head_dim: int = 64                 # mamba2 heads
+    ssm_groups: int = 1                    # mamba2 B/C groups (n_groups)
+    ssm_chunk: int = 256                   # mamba2 SSD chunk length
 
     # --- hybrid (zamba2) ---
     attn_every: int = 0                    # insert shared attn block every N
     n_shared_attn_blocks: int = 0          # distinct shared blocks, cycled
+
+    # --- hybrid by layer pattern (granite-4.0-h) ---
+    layer_types: tuple[str, ...] = ()      # per layer "mamba" | "attention"
+    #   mixer, each followed by the MLP; empty: zamba2's shared blocks
 
     # --- multimodal stubs ---
     cross_attn_every: int = 0              # vlm: cross-attn block every N
@@ -61,6 +71,10 @@ class ModelConfig:
     act: str = "silu"                      # silu | gelu
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
+    # granite multipliers; 0 / 1 leave each family's default in place
+    embedding_multiplier: float = 0.0      # input embedding scale
+    residual_multiplier: float = 1.0       # scale of each block's output
+    logits_scaling: float = 1.0            # logits are divided by this
 
     # --- framework features ---
     remat_policy: str = "dots"             # none | dots | full
@@ -75,6 +89,9 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"{self.name}: {len(self.layer_types)} "
+                             f"layer_types for {self.n_layers} layers")
 
     @property
     def is_attention_free(self) -> bool:
@@ -99,25 +116,32 @@ class ModelConfig:
         """The same widths with only ``n_layers`` layers: a depth-only cut
         that keeps every per-layer shape (and so the per-layer cost) as
         published.  The cut must keep whole layer groups."""
+        period = (self.n_layers // self.layer_types.count("attention")
+                  if self.layer_types else 1)
         group = max(self.attn_every, self.cross_attn_every,
-                    self.moe_every if self.n_experts else 1, 1)
+                    self.moe_every if self.n_experts else 1, period, 1)
         if not 0 < n_layers <= self.n_layers or n_layers % group:
             raise ValueError(
                 f"{self.name}: cannot cut {self.n_layers} layers to "
                 f"{n_layers} (a positive multiple of {group}, at most "
                 f"{self.n_layers})")
         return dataclasses.replace(self, name=f"{self.name}-{n_layers}L",
-                                   n_layers=n_layers)
+                                   n_layers=n_layers,
+                                   layer_types=self.layer_types[:n_layers])
 
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         def cut(v, lo=1):
             return max(lo, v)
+        # two layers of each mixer, a mamba layer first as published
+        pattern = (("mamba", "attention") * 2 if self.layer_types else ())
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=min(self.n_layers, 4 if self.attn_every or
-                         self.cross_attn_every else 2),
+            n_layers=len(pattern) or min(
+                self.n_layers, 4 if self.attn_every or
+                self.cross_attn_every else 2),
+            layer_types=pattern,
             d_model=64,
             n_heads=cut(min(self.n_heads, 4)),
             n_kv_heads=cut(min(self.n_kv_heads, 2)),
@@ -133,6 +157,7 @@ class ModelConfig:
             shared_expert_d_ff=64 if self.shared_expert_d_ff else 0,
             ssm_state=min(self.ssm_state, 8) if self.ssm_state else 0,
             ssm_head_dim=16 if self.family in ("ssm", "hybrid") else 64,
+            ssm_chunk=min(self.ssm_chunk, 8),
             attn_every=min(self.attn_every, 2) if self.attn_every else 0,
             cross_attn_every=min(self.cross_attn_every, 2)
             if self.cross_attn_every else 0,

@@ -17,6 +17,7 @@ _MODULES = {
     "zamba2-2.7b": "zamba2_2_7b",
     "falcon-mamba-7b": "falcon_mamba_7b",
     "llama-3.2-vision-11b": "llama_3_2_vision_11b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCHS = tuple(_MODULES)

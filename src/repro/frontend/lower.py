@@ -214,6 +214,9 @@ def _layer_kind(cfg: ModelConfig, layer: int) -> str:
     """attn+mlp | moe | ssm for one layer index of the config."""
     if cfg.family == "ssm":
         return "ssm"
+    if cfg.layer_types:
+        return "attn" if cfg.layer_types[layer % cfg.n_layers] == \
+            "attention" else "ssm"
     if cfg.family == "hybrid":
         every = max(1, cfg.attn_every or 1)
         return "attn" if cfg.attn_every and layer % every == every - 1 \

@@ -83,6 +83,7 @@ class AttnSpec:
     window: int = 0               # >0: sliding window size
     softcap: float = 0.0
     kv_block: int = 512
+    scale: float = 0.0            # score scale; 0 -> 1/sqrt(head_dim)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -107,7 +108,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    scale = Dh ** -0.5
+    scale = spec.scale or Dh ** -0.5
     qg = (q.astype(jnp.float32) * scale).reshape(B, Tq, K, G, Dh)
     kb = k.reshape(B, nblk, blk, K, Dh).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(B, nblk, blk, K, Dh).transpose(1, 0, 2, 3, 4)
@@ -157,13 +158,14 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q: (B, 1, H, Dh), the token at position ``pos``; k, v: (B, S, K, Dh),
     the cache with that token's K/V written.  The same numbers as
     :func:`attention` with ``q_offset=pos`` and ``kv_len=pos + 1`` (float32
-    scores, softmax and accumulation, the same masks and softcap), but the
-    two dots read the cache in its own layout: no block reshape or
+    scores, softmax and accumulation, the same scale, masks and softcap),
+    but the two dots read the cache in its own layout: no block reshape or
     transpose, so the compiler reads it in place.
     """
     B, _, H, Dh = q.shape
     S, K = k.shape[1], k.shape[2]
-    qg = (q[:, 0].astype(jnp.float32) * Dh ** -0.5).reshape(B, K, H // K, Dh)
+    scale = spec.scale or Dh ** -0.5
+    qg = (q[:, 0].astype(jnp.float32) * scale).reshape(B, K, H // K, Dh)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32))
     s = _softcap(s, spec.softcap)
     delta = jnp.asarray(pos) - jnp.arange(S)                         # (S,)

@@ -11,9 +11,13 @@
 Layer stacks are scanned (``jax.lax.scan`` over stacked params) so the HLO
 stays compact for the 512-device dry-run; heterogeneous schedules (gemma
 local/global, zamba2 shared attention, llama-vision cross blocks) are
-expressed as scanned per-layer flags or group-structured scans.  The one
-unrolled tower is the decoder stack's ``decode_step``: there each layer
-updates and reads the stacked KV cache in place, at a constant index.
+expressed as scanned per-layer flags or group-structured scans.  The
+unrolled towers are the ``decode_step`` of the decoder stack and of the
+hybrids: there each layer updates and reads its part of the stacked cache
+(KV, or a mamba layer's conv window and SSM state) in place, at a constant
+index.  The hybrids' other passes scan their schedule of layer kinds
+(granite-4.0-h's ``layer_types``, zamba2's shared blocks) with a
+``lax.switch`` per step.
 """
 
 from __future__ import annotations
@@ -84,7 +88,16 @@ class Model:
             p["blocks"] = _stack_init(
                 lambda k: self._init_ssm_block(k, dtype), keys[2],
                 cfg.n_layers)
-        if cfg.family == "hybrid":
+        if cfg.family == "hybrid" and cfg.layer_types:
+            # mamba layers and attention layers, each kind stacked in
+            # layer order; each carries its MLP
+            n_attn = cfg.layer_types.count("attention")
+            p["blocks"] = _stack_init(
+                lambda k: self._init_ssm_block(k, dtype), keys[2],
+                cfg.n_layers - n_attn)
+            p["attn_blocks"] = _stack_init(
+                lambda k: self._init_block(k, dtype), keys[3], n_attn)
+        elif cfg.family == "hybrid":
             p["blocks"] = _stack_init(
                 lambda k: self._init_ssm_block(k, dtype), keys[2],
                 cfg.n_layers)
@@ -106,7 +119,9 @@ class Model:
         cfg = self.cfg
         return AttnSpec(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                         window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap)
+                        softcap=cfg.attn_logit_softcap,
+                        kv_block=cfg.attn_kv_block,
+                        scale=cfg.attention_multiplier)
 
     def _init_block(self, key, dtype, kind: str | None = None) -> Params:
         cfg = self.cfg
@@ -153,8 +168,14 @@ class Model:
 
     def _init_ssm_block(self, key, dtype) -> Params:
         cfg = self.cfg
-        return {"ln": jnp.zeros((cfg.d_model,), dtype),
-                "mixer": ssm.init_mamba_params(key, cfg, dtype)}
+        ks = jax.random.split(key, 2)
+        p = {"ln": jnp.zeros((cfg.d_model,), dtype),
+             "mixer": ssm.init_mamba_params(ks[0], cfg, dtype)}
+        if cfg.layer_types:
+            p["ln2"] = jnp.zeros((cfg.d_model,), dtype)
+            p["mlp"] = layers.init_mlp_params(ks[1], cfg.d_model, cfg.d_ff,
+                                              dtype)
+        return p
 
     # ---------------- per-layer flags ----------------
 
@@ -168,13 +189,24 @@ class Model:
 
     # ---------------- forward (train / prefill) ----------------
 
+    def _scale_embedding(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        if cfg.embedding_multiplier:
+            return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        if cfg.family == "audio" or (cfg.family == "dense"
+                                     and cfg.tie_embeddings):
+            return x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        return x
+
+    def _residual(self, y: jax.Array) -> jax.Array:
+        """A block's output as it joins the residual stream."""
+        m = self.cfg.residual_multiplier
+        return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
+
     @jax.named_scope(layers.EMBED)
     def embed_inputs(self, params: Params, batch: dict) -> jax.Array:
         cfg = self.cfg
-        x = params["embed"][batch["tokens"]]
-        if cfg.family == "dense" and cfg.tie_embeddings or cfg.family in (
-                "audio",):
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = self._scale_embedding(params["embed"][batch["tokens"]])
         if cfg.family == "audio":
             media = jnp.einsum("bmd,dk->bmk", batch["media"].astype(x.dtype),
                                params["media_proj"])
@@ -194,7 +226,7 @@ class Model:
         elif cfg.family == "ssm":
             x = self._run_ssm(params, x)
         elif cfg.family == "hybrid":
-            x = self._run_hybrid(params, x, positions)
+            x, _ = self._hybrid_scan(params, None, x, positions)
         x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         if cfg.family == "audio":
             x = x[:, cfg.n_media_tokens:]           # strip conditioning frames
@@ -206,6 +238,8 @@ class Model:
         cfg = self.cfg
         w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
         logits = jnp.einsum("btd,dv->btv", x, w.astype(x.dtype))
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         if cfg.final_logit_softcap:
             logits = (cfg.final_logit_softcap
                       * jnp.tanh(logits / cfg.final_logit_softcap))
@@ -230,16 +264,17 @@ class Model:
             blk["attn"], h, spec, rope_theta=cfg.rope_theta,
             norm_eps=cfg.norm_eps, positions=positions, is_global=is_global,
             kv_cache=kv_cache, cache_len=cache_len, layer=layer,
-            use_rope=cfg.family != "audio",
+            use_rope=cfg.family != "audio"
+            and cfg.position_embedding == "rope",
             constrain_dp=cfg.constrain_internals)
-        x = x + a
+        x = x + self._residual(a)
         h = layers.rms_norm(x, blk["ln2"], cfg.norm_eps)
         if "moe" in blk:
             x = x + moe.moe_block(blk["moe"], h, cfg)
         else:
-            x = x + layers.mlp_block(blk["mlp"], h, cfg.act,
-                                     overlap=cfg.overlap == "shared_bus",
-                                     constrain_dp=cfg.constrain_internals)
+            x = x + self._residual(layers.mlp_block(
+                blk["mlp"], h, cfg.act, overlap=cfg.overlap == "shared_bus",
+                constrain_dp=cfg.constrain_internals))
         return x, kv
 
     def _run_decoder(self, params, x, positions):
@@ -311,14 +346,16 @@ class Model:
         return x
 
     @jax.named_scope(layers.SSM)
-    def _ssm_layer(self, blk, x, state=None):
+    def _ssm_layer(self, blk, x, state=None, mask=None):
         cfg = self.cfg
-        mixer = ssm.mamba1_block if cfg.mamba_version == 1 else \
-            ssm.mamba2_block
         x = self._constrain_residual(x)
         h = layers.rms_norm(x, blk["ln"], cfg.norm_eps)
-        y, new_state = mixer(blk["mixer"], h, cfg, state=state)
-        return x + y, new_state
+        if cfg.mamba_version == 1:
+            y, new_state = ssm.mamba1_block(blk["mixer"], h, cfg, state=state)
+        else:
+            y, new_state = ssm.mamba2_block(blk["mixer"], h, cfg,
+                                            state=state, mask=mask)
+        return x + self._residual(y), new_state
 
     def _run_ssm(self, params, x):
         def layer(x, blk):
@@ -329,37 +366,157 @@ class Model:
         x, _ = self._scan(layer, x, params["blocks"])
         return x
 
-    def _run_hybrid(self, params, x, positions):
+    def _mamba_layer(self, blk, x, state=None, mask=None):
+        """One mamba layer, and for granite-4.0-h the MLP after it.
+        ``state``: its (conv window, SSM state), or None (no cache)."""
         cfg = self.cfg
-        k = cfg.attn_every
-        n_groups = cfg.n_layers // k
-        blocks = jax.tree.map(
-            lambda a: a.reshape(n_groups, k, *a.shape[1:]), params["blocks"])
+        x, state = self._ssm_layer(blk, x, state=state, mask=mask)
+        if "mlp" in blk:
+            hn = layers.rms_norm(x, blk["ln2"], cfg.norm_eps)
+            x = x + self._residual(layers.mlp_block(
+                blk["mlp"], hn, cfg.act, overlap=cfg.overlap == "shared_bus"))
+        return x, state
 
-        def group(x, inp):
-            grp, g_idx = inp
+    def _mamba_decode_layer(self, blk, x, conv, h, layer):
+        """``_mamba_layer`` over every mamba layer's stacked conv windows
+        and SSM states, of which this layer's are at ``layer``: read there
+        and written back there, in place where the compiler sees a
+        constant index."""
+        x, (nc, nh) = self._mamba_layer(blk, x, (conv[layer], h[layer]))
+        with jax.named_scope(layers.SSM):
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, nc.astype(conv.dtype), layer, 0)
+            h = jax.lax.dynamic_update_index_in_dim(h, nh, layer, 0)
+        return x, conv, h
 
-            def inner(x, blk):
-                x, _ = self._ssm_layer(blk, x)
-                return x, None
+    def _attn_decode_layer(self, blk, x, positions, k, v, pos, layer):
+        """One attention layer over the stacked K/V caches at ``layer``."""
+        x, (k, v) = self._decoder_layer(blk, x, positions, True,
+                                        kv_cache=(k, v), cache_len=pos,
+                                        layer=layer)
+        return x, k, v
 
-            x, _ = self._scan(inner, x, grp)
-            # shared attention block, cycled over the distinct weight sets
-            sa = _take(params["shared_attn"],
-                       g_idx % cfg.n_shared_attn_blocks)
-            h = layers.rms_norm(x, sa["ln"], cfg.norm_eps)
-            a, _ = layers.attn_block(
-                sa["attn"], h, self._attn_spec(), rope_theta=cfg.rope_theta,
-                norm_eps=cfg.norm_eps, positions=positions)
-            x = x + a
-            h = layers.rms_norm(x, sa["ln2"], cfg.norm_eps)
-            x = x + layers.mlp_block(sa["mlp"], h, cfg.act,
-                                     overlap=cfg.overlap == "shared_bus")
-            return x, None
+    def _hybrid_schedule(self) -> list[tuple[str, int]]:
+        """The hybrid stack in order: (kind, index in its weight stack).
+        granite-4.0-h: each layer's mixer, "mamba" or "attention";
+        zamba2: every mamba layer, and after each ``attn_every``-th one
+        a "shared" block (its cache index is its place among them)."""
+        cfg = self.cfg
+        if cfg.layer_types:
+            seen = {"mamba": 0, "attention": 0}
+            out = []
+            for kind in cfg.layer_types:
+                out.append((kind, seen[kind]))
+                seen[kind] += 1
+            return out
+        out = []
+        for i in range(cfg.n_layers):
+            out.append(("mamba", i))
+            if i % cfg.attn_every == cfg.attn_every - 1:
+                out.append(("shared", i // cfg.attn_every))
+        return out
 
-        group = layers.maybe_remat(group, cfg.remat_policy)
-        x, _ = self._scan(group, x, (blocks, jnp.arange(n_groups)))
-        return x
+    def _hybrid_block(self, params, kind, i):
+        """The weights of one step of the hybrid schedule (``i`` may be
+        traced)."""
+        if kind == "mamba":
+            return _take(params["blocks"], i)
+        if kind == "attention":
+            return _take(params["attn_blocks"], i)
+        # zamba2's shared block, cycled over its weight sets
+        sa = _take(params["shared_attn"], i % self.cfg.n_shared_attn_blocks)
+        return {**sa, "ln1": sa["ln"]}
+
+    def _hybrid_scan(self, params, cache, x, positions, mask=None):
+        """Prefill and training over a hybrid stack: one scan over the
+        schedule, each step the layer of its kind (``lax.switch``) with its
+        weights at the step's index.  With a cache, a step reads its
+        layer's slots (a mamba layer's conv window and SSM state, an
+        attention layer's K/V) out of the stacked caches, the switch
+        computes on those slots alone, and the step writes them back in
+        place; a step of the other kind writes back what it read.  So the
+        stacks, carried by the scan, never enter a branch, where the one
+        that leaves a stack unchanged would copy it whole.  Returns
+        (x, cache); without a cache nothing is cached."""
+        cfg = self.cfg
+        steps = self._hybrid_schedule()
+        kinds = [k for k in ("mamba", "attention", "shared")
+                 if any(kind == k for kind, _ in steps)]
+        cached = cache is not None
+        pos = cache["pos"] if cached else None
+
+        def mamba(x, slots, i):
+            conv, h, k, v = slots
+            blk = self._hybrid_block(params, "mamba", i)
+            x, state = self._mamba_layer(blk, x, (conv, h) if cached
+                                         else None, mask)
+            return x, (*state, k, v) if cached else slots
+
+        def attention(kind):
+            def run(x, slots, i):
+                conv, h, k, v = slots
+                x, kv = self._decoder_layer(
+                    self._hybrid_block(params, kind, i), x, positions, True,
+                    kv_cache=(k, v) if cached else None, cache_len=pos)
+                return x, (conv, h, *kv) if cached else slots
+            return run
+
+        branches = [mamba if k == "mamba" else attention(k) for k in kinds]
+
+        def layer(carry, step):
+            x, stacks = carry
+            kind, i, at = step                     # at: (mamba, KV) slot
+            slots = ((stacks[0][at[0]], stacks[1][at[0]], stacks[2][at[1]],
+                      stacks[3][at[1]]) if cached else (None,) * 4)
+            x, slots = jax.lax.switch(kind, branches, x, slots, i)
+            if cached:
+                conv, h, k, v = stacks
+                with jax.named_scope(layers.SSM):
+                    conv = jax.lax.dynamic_update_index_in_dim(
+                        conv, slots[0].astype(conv.dtype), at[0], 0)
+                    h = jax.lax.dynamic_update_index_in_dim(
+                        h, slots[1], at[0], 0)
+                with jax.named_scope(layers.ATTENTION), \
+                        jax.named_scope(layers.KV_CACHE):
+                    k = jax.lax.dynamic_update_index_in_dim(
+                        k, slots[2], at[1], 0)
+                    v = jax.lax.dynamic_update_index_in_dim(
+                        v, slots[3], at[1], 0)
+                stacks = (conv, h, k, v)
+            return (x, stacks), None
+
+        # each step's slots: its own, and for the other kind the last seen
+        at, last = [], {"mamba": 0, "kv": 0}
+        for kind, i in steps:
+            last["mamba" if kind == "mamba" else "kv"] = i
+            at.append((last["mamba"], last["kv"]))
+        sched = (jnp.asarray([kinds.index(k) for k, _ in steps]),
+                 jnp.asarray([i for _, i in steps]), jnp.asarray(at))
+        stacks = (tuple(cache[n] for n in ("conv", "h", "k", "v"))
+                  if cached else None)
+        layer = layers.maybe_remat(layer, cfg.remat_policy)
+        (x, stacks), _ = self._scan(layer, (x, stacks), sched)
+        if cached:
+            cache = {**cache, **dict(zip(("conv", "h", "k", "v"), stacks))}
+        return x, cache
+
+    def _hybrid_decode(self, params, cache, x, positions):
+        """One decode step through a hybrid stack, unrolled: each layer is
+        one call of a jitted function traced once per kind, which reads
+        and writes its own slot of the stacked cache at an index the
+        compiler sees as a constant, in place.  No layer's state is sliced
+        out as a scan's xs or stacked again as its ys."""
+        mamba = jax.jit(self._mamba_decode_layer)
+        attn = jax.jit(self._attn_decode_layer)
+        conv, h, k, v = (cache[n] for n in ("conv", "h", "k", "v"))
+        for kind, i in self._hybrid_schedule():
+            blk = self._hybrid_block(params, kind, i)
+            if kind == "mamba":
+                x, conv, h = mamba(blk, x, conv, h, jnp.int32(i))
+            else:
+                x, k, v = attn(blk, x, positions, k, v, cache["pos"],
+                               jnp.int32(i))
+        return x, {**cache, "conv": conv, "h": h, "k": k, "v": v}
 
     # ---------------- loss ----------------
 
@@ -380,9 +537,12 @@ class Model:
     # ---------------- prefill ----------------
 
     def prefill(self, params: Params, cache: dict, tokens: jax.Array,
-                media: jax.Array | None = None) -> tuple[jax.Array, dict]:
+                media: jax.Array | None = None,
+                mask: jax.Array | None = None) -> tuple[jax.Array, dict]:
         """Fill the decode cache from a (B, T) prompt; returns last-position
-        logits and the cache positioned at T."""
+        logits and the cache positioned at T.  ``mask`` (B, T), false at
+        padding: the hybrids' mamba layers leave their state unchanged
+        there (attention attends to it, as in the other families)."""
         cfg = self.cfg
         T = tokens.shape[1]
         batch = {"tokens": tokens}
@@ -431,7 +591,7 @@ class Model:
                 layer, x, (params["blocks"], cache["conv"], cache["h"]))
             cache = {**cache, "conv": nc, "h": nh}
         elif cfg.family == "hybrid":
-            x, cache = self._decode_hybrid(params, cache, x, positions)
+            x, cache = self._hybrid_scan(params, cache, x, positions, mask)
 
         x = layers.rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
@@ -456,19 +616,27 @@ class Model:
                 (n_cross, batch_size, cfg.n_media_tokens, K, Dh), dtype)
             cache["media_v"] = jnp.zeros_like(cache["media_k"])
         if cfg.family in ("ssm", "hybrid"):
+            # conv window and SSM state for the mamba layers only, K/V for
+            # the attention layers only
+            kinds = ([k for k, _ in self._hybrid_schedule()]
+                     if cfg.family == "hybrid" else ["mamba"] * L)
+            n_ssm = kinds.count("mamba")
+            n_attn = len(kinds) - n_ssm
             di, n = cfg.d_inner, cfg.ssm_state
             cache["conv"] = jnp.zeros(
-                (L, batch_size, cfg.ssm_conv - 1, di), dtype)
+                (n_ssm, batch_size, cfg.ssm_conv - 1, ssm.conv_width(cfg)),
+                dtype)
             if cfg.mamba_version == 1:
-                cache["h"] = jnp.zeros((L, batch_size, di, n), jnp.float32)
+                cache["h"] = jnp.zeros((n_ssm, batch_size, di, n),
+                                       jnp.float32)
             else:
                 H = di // cfg.ssm_head_dim
                 cache["h"] = jnp.zeros(
-                    (L, batch_size, H, cfg.ssm_head_dim, n), jnp.float32)
-        if cfg.family == "hybrid":
-            n_app = cfg.n_layers // cfg.attn_every
-            cache["k"] = jnp.zeros((n_app, batch_size, max_len, K, Dh), dtype)
-            cache["v"] = jnp.zeros_like(cache["k"])
+                    (n_ssm, batch_size, H, cfg.ssm_head_dim, n), jnp.float32)
+            if n_attn:
+                cache["k"] = jnp.zeros(
+                    (n_attn, batch_size, max_len, K, Dh), dtype)
+                cache["v"] = jnp.zeros_like(cache["k"])
         return cache
 
     def decode_step(self, params: Params, cache: dict, tokens: jax.Array,
@@ -477,10 +645,7 @@ class Model:
         """One serve step: tokens (B, 1) -> logits (B, 1, V), updated cache."""
         cfg = self.cfg
         with jax.named_scope(layers.EMBED):
-            x = params["embed"][tokens]
-            if cfg.family == "audio" or (cfg.family == "dense"
-                                         and cfg.tie_embeddings):
-                x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+            x = self._scale_embedding(params["embed"][tokens])
         pos = cache["pos"]
         B = tokens.shape[0]
         positions = jnp.full((B, 1), pos, jnp.int32)
@@ -516,7 +681,7 @@ class Model:
                 layer, x, (params["blocks"], cache["conv"], cache["h"]))
             cache = {**cache, "conv": nc, "h": nh}
         elif cfg.family == "hybrid":
-            x, cache = self._decode_hybrid(params, cache, x, positions)
+            x, cache = self._hybrid_decode(params, cache, x, positions)
 
         x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
@@ -598,47 +763,6 @@ class Model:
         cache = {**cache,
                  "k": nk.reshape(cfg.n_layers, *nk.shape[2:]),
                  "v": nv.reshape(cfg.n_layers, *nv.shape[2:])}
-        return x, cache
-
-    def _decode_hybrid(self, params, cache, x, positions):
-        cfg = self.cfg
-        k = cfg.attn_every
-        n_groups = cfg.n_layers // k
-        pos = cache["pos"]
-        blocks = jax.tree.map(
-            lambda a: a.reshape(n_groups, k, *a.shape[1:]), params["blocks"])
-        convr = cache["conv"].reshape(n_groups, k, *cache["conv"].shape[1:])
-        hr = cache["h"].reshape(n_groups, k, *cache["h"].shape[1:])
-
-        def group(x, inp):
-            grp, conv, h, kc, vc, g_idx = inp
-
-            def inner(x, st):
-                blk, c, hh = st
-                x, (nc, nh) = self._ssm_layer(blk, x, state=(c, hh))
-                return x, (nc, nh)
-
-            x, (nc, nh) = self._scan(inner, x, (grp, conv, h))
-            sa = _take(params["shared_attn"],
-                       g_idx % cfg.n_shared_attn_blocks)
-            hn = layers.rms_norm(x, sa["ln"], cfg.norm_eps)
-            a, (nk, nv) = layers.attn_block(
-                sa["attn"], hn, self._attn_spec(), rope_theta=cfg.rope_theta,
-                norm_eps=cfg.norm_eps, positions=positions,
-                kv_cache=(kc, vc), cache_len=pos)
-            x = x + a
-            hn = layers.rms_norm(x, sa["ln2"], cfg.norm_eps)
-            x = x + layers.mlp_block(sa["mlp"], hn, cfg.act,
-                                     overlap=cfg.overlap == "shared_bus")
-            return x, (nc, nh, nk, nv)
-
-        x, (nc, nh, nk, nv) = self._scan(
-            group, x, (blocks, convr, hr, cache["k"], cache["v"],
-                       jnp.arange(n_groups)))
-        cache = {**cache,
-                 "conv": nc.reshape(cfg.n_layers, *nc.shape[2:]),
-                 "h": nh.reshape(cfg.n_layers, *nh.shape[2:]),
-                 "k": nk, "v": nv}
         return x, cache
 
 

@@ -8,19 +8,23 @@ What an operator can see: run ``generate`` under ``jax.profiler.trace`` and
 the host thread that calls it shows the spans ``engine.generate`` (the whole
 call), ``engine.admit`` (padding, token array, cache, media),
 ``engine.prefill`` (prefill and the first sample) and, per decode
-iteration, ``engine.read_tokens`` (the per-slot token reads, EOS
+iteration, ``engine.read_tokens`` (the batch's token read, EOS
 bookkeeping and stop test) and ``engine.decode`` (key, ``decode_step`` and
 sample).  Each carries ``batch=<n>``, the engine's count of ``generate``
 calls; the per-iteration ones also ``step=<k>``.  The counters in
 ``Engine.metrics.snapshot()["counters"]`` are ``engine.host_reads``
 (device-to-host reads), ``engine.decode_steps`` (``decode_step`` calls) and
 ``engine.decode_steps_kept`` (calls whose sampled token some request
-appended).
+appended).  The gauges ``engine.cache_bytes.kv`` and
+``engine.cache_bytes.state`` record, as each batch's cache is built, the
+bytes it holds for attention keys and values and for recurrent state (a
+mamba layer's conv window and SSM state).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +79,12 @@ class Engine:
                 toks = np.zeros((B, plen), np.int32)
                 for i, p in enumerate(prompts):
                     toks[i, plen - len(p):] = p          # left-pad
+                # where there is padding, prefill is told where it lies
+                lens = np.array([len(p) for p in prompts])
+                pad = ((jnp.asarray(np.arange(plen) >= plen - lens[:, None]),)
+                       if lens.min() < plen else ())
                 cache = self.model.init_cache(B, cfg.max_len)
+                self._record_cache(cache)
                 m = (jnp.asarray(media) if media is not None else
                      (jnp.zeros((B, self.model.cfg.n_media_tokens,
                                  self.model.cfg.media_embed_dim),
@@ -83,26 +92,27 @@ class Engine:
                       if self.model.cfg.n_media_tokens else None))
             with jax.profiler.TraceAnnotation("engine.prefill", batch=n):
                 logits, cache = self.prefill(self.params, cache,
-                                             jnp.asarray(toks), m)
+                                             jnp.asarray(toks), m, *pad)
                 key = jax.random.key(cfg.seed)
                 cur = self._sample(logits, key)
             out = [list(p) for p in prompts]
             done = np.zeros(B, bool)
+            # the cache's position, read once: each decode step adds one
+            pos = int(cache["pos"])
+            reads.inc()
             for step in range(max_new):
                 with jax.profiler.TraceAnnotation("engine.read_tokens",
                                                   batch=n, step=step):
+                    # the whole batch's tokens in one device-to-host read
+                    new = np.asarray(cur)[:, 0]
+                    reads.inc()
                     appended = 0
-                    for i in range(B):
-                        if not done[i]:
-                            t = int(cur[i, 0])
-                            out[i].append(t)
-                            done[i] |= t == cfg.eos_token
-                            appended += 1
-                    reads.inc(appended)
-                    stop = done.all()
-                    if not stop:
-                        reads.inc()
-                        stop = int(cache["pos"]) >= cfg.max_len - 1
+                    for i in np.flatnonzero(~done):
+                        t = int(new[i])
+                        out[i].append(t)
+                        done[i] |= t == cfg.eos_token
+                        appended += 1
+                    stop = done.all() or pos >= cfg.max_len - 1
                 if step and appended:
                     kept.inc()
                 if stop:
@@ -112,8 +122,16 @@ class Engine:
                     key = jax.random.fold_in(key, step)
                     logits, cache = self.decode(self.params, cache, cur, m)
                     cur = self._sample(logits, key)
+                    pos += 1
                 steps.inc()
         return out
+
+    def _record_cache(self, cache: dict) -> None:
+        now = time.perf_counter_ns()
+        for kind, names in (("kv", ("k", "v", "media_k", "media_v")),
+                            ("state", ("conv", "h"))):
+            self.metrics.gauge(f"engine.cache_bytes.{kind}").record(
+                now, sum(cache[n].nbytes for n in names if n in cache))
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         lg = logits[:, -1, :]

@@ -43,9 +43,7 @@ _NAME_RULES: dict[str, list[tuple[int, str]]] = {
     "in_proj": [(1, "model"), (0, "data")],
     "out_proj": [(0, "model"), (1, "data")],
     "x_proj": [(0, "model")],
-    "bc_proj": [(0, "data")],
     "dt_proj": [(1, "model")],
-    "dt_proj_h": [(0, "data")],
     "conv_w": [(1, "model")],
     "conv_b": [(0, "model")],
     "A_log": [(0, "model")],
@@ -70,10 +68,11 @@ def _path_has(path, *names) -> bool:
 
 
 def _stacked(path) -> bool:
-    """Leaves under blocks/moe_blocks/cross_blocks/shared_attn carry a
-    leading layer-stack dimension that must never be sharded (scan axis)."""
+    """Leaves under blocks/moe_blocks/cross_blocks/shared_attn/attn_blocks
+    carry a leading layer-stack dimension that must never be sharded (scan
+    axis)."""
     return _path_has(path, "blocks", "moe_blocks", "cross_blocks",
-                     "shared_attn")
+                     "shared_attn", "attn_blocks")
 
 
 def param_spec(path, shape: tuple[int, ...], mesh: Mesh) -> P:

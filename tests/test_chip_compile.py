@@ -177,6 +177,121 @@ def test_decode_step_updates_the_cache_in_place(serve_decode):
     assert len(big) - len(in_place) == 0, big
 
 
+def _instructions(compiled) -> str:
+    """The instruction lines of a compiled program, without metadata: what
+    the chip runs, apart from names of source lines and scopes."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", compiled.as_text())
+    return "\n".join(line for line in text.splitlines()
+                     if re.match(r"\s*(ROOT |ENTRY )?%", line))
+
+
+# sha256 of ``_instructions`` of granite-3-2b's programs as compiled before
+# the layer-pattern hybrid (granite-4.0-h) came into the model code, with
+# jax 0.9.0 and its TPU compiler: the hybrid changes no dense program
+DENSE_PROGRAMS = {
+    "decode_step": "37d14987a704186952f85bc8c74a159d"
+                   "71d08cd7f9467d1913275b1b771b1ff5",
+    "prefill": "595b9d6a1c36f4713f7395a1663c49ae"
+               "9641e4ca7c0deb90f899baeef8096e58",
+}
+
+
+def test_dense_programs_are_unchanged(serving, serve_decode):
+    import hashlib
+
+    got = {"decode_step": _instructions(serve_decode[0]),
+           "prefill": _instructions(serving["prefill"])}
+    assert {k: hashlib.sha256(v.encode()).hexdigest()
+            for k, v in got.items()} == DENSE_PROGRAMS
+
+
+def _fusion_roots(text: str) -> dict[str, str]:
+    """Fusion instruction name -> opcode of its fused computation's root."""
+    roots, computation = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and not line.startswith(" "):
+            computation = head.group(1)
+        root = re.match(r"\s*ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if root:
+            roots[computation] = root.group(1)
+    calls = re.findall(r"(%[\w.\-]+) = \S+ fusion\(.*calls=(%[\w.\-]+)",
+                       text)
+    return {name: roots.get(called, "") for name, called in calls}
+
+
+@pytest.fixture(scope="module")
+def hybrid_decode(one_chip):
+    """granite-4.0-h-micro's decode program as the engine jits it, at the
+    serve-chat-b32 cell's shapes: 32 requests over a 2304-token cache."""
+    model = model_lib.build(registry.get("granite-4.0-h-micro"))
+    params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
+                     one_chip)
+    batch, max_len = 32, 2304
+    engine = Engine(model, params, ServeConfig(max_batch=batch,
+                                               max_len=max_len))
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(batch, max_len)),
+                    one_chip)
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    return engine.decode.lower(params, cache, tokens, None).compile(), cache
+
+
+def test_hybrid_decode_updates_its_state_in_place(hybrid_decode):
+    """The donated cache is the new one: each of the 36 mamba layers
+    writes its SSM state back where it lies (an in-place
+    dynamic-update-slice fusion that computes the new state as it writes
+    it) and each of the 4 attention layers its token's K and V.  No other
+    instruction has an output of a layer's SSM state or more: no state is
+    sliced out, copied or stacked again."""
+    compiled, cache = hybrid_decode
+    _fits(compiled)
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= sum(a.size * a.dtype.itemsize
+                   for n, a in cache.items() if n != "pos"))
+    h = cache["h"]
+    state_bytes = h.size // h.shape[0] * h.dtype.itemsize
+    text = compiled.as_text()
+    ops = _top_level(text)
+    size = {name: n for _, n, name, _ in ops}
+    roots = _fusion_roots(text)
+    big = [(op, name, operands) for op, n, name, operands in ops
+           if n >= state_bytes
+           and op not in ("parameter", "get-tuple-element", "bitcast")]
+    state = [name for op, name, _ in big if op == "fusion"
+             and roots.get(name) == "dynamic-update-slice"
+             and size[name] == h.size * h.dtype.itemsize]
+    kv = [name for op, name, operands in big
+          if op == "dynamic-update-slice"
+          and size.get(operands[1], 0) < state_bytes]
+    assert len(state) == 36
+    assert len(kv) == 2 * 4
+    assert len(big) == len(state) + len(kv), big
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_hybrid_programs_keep_layer_scopes(hybrid_decode, one_chip,
+                                           program):
+    """The mamba layers' work is named ``ssm``, beside the kinds the dense
+    programs show; prefill at a small size, the scopes are the same."""
+    if program == "decode_step":
+        compiled = hybrid_decode[0]
+    else:
+        cfg = registry.get("granite-4.0-h-micro")
+        model = model_lib.build(cfg.with_depth(10))
+        params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
+                         one_chip)
+        cache = _placed(jax.eval_shape(lambda: model.init_cache(2, 512)),
+                        one_chip)
+        tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32,
+                                      sharding=one_chip)
+        compiled = jax.jit(model.prefill).lower(params, cache, tokens,
+                                                None).compile()
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    kinds = {layer_trace.kind_of(n) for n in names}
+    assert {"embed", "ssm", "attention", "kv_cache", "mlp",
+            "unembed"} <= kinds
+
+
 def test_train_step_compiles_for_one_chip(topo):
     """The train launcher's donated step at published widths, cut to 2
     layers and a small batch so that it compiles in seconds."""

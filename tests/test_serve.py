@@ -22,7 +22,8 @@ def _engine(arch, dtype=None, **kw):
 
 
 @pytest.mark.parametrize("arch", ["granite-3-2b", "falcon-mamba-7b",
-                                  "zamba2-2.7b", "qwen2-moe-a2.7b"])
+                                  "zamba2-2.7b", "qwen2-moe-a2.7b",
+                                  "granite-4.0-h-micro"])
 def test_generate_batch(arch):
     cfg, eng = _engine(arch)
     rng = np.random.default_rng(0)
@@ -54,6 +55,9 @@ def test_greedy_deterministic():
     ("gemma2-9b", 12, "float32"),
     # audio: media frames ahead of the tokens, no rotary embeddings
     ("musicgen-medium", 5, None),
+    # mamba-2 and NoPE attention layers; 11 tokens span an SSD chunk (8)
+    # and part of the next.  In float32, as gemma2's
+    ("granite-4.0-h-micro", 11, "float32"),
 ])
 def test_greedy_matches_teacher_forcing(arch, prompt_len, dtype):
     """Engine decode, over its donated cache, must agree with argmax over
@@ -130,21 +134,39 @@ def test_generate_emits_engine_spans(batch, max_new):
 
 @pytest.mark.parametrize("batch,max_new", [(1, 2), (4, 5), (3, 1)])
 def test_engine_counters(batch, max_new):
-    """B token reads plus the position read per decode iteration, one
-    decode_step per iteration, and the last one's token never kept."""
+    """One read of the position per call and one of the batch's tokens per
+    decode iteration, one decode_step per iteration, and the last one's
+    token never kept."""
     cfg, eng = _no_eos_engine()
     prompts = [[2 + i, 3, 4] for i in range(batch)]
     for calls in (1, 2):
         eng.generate(prompts, max_new=max_new)
         c = eng.metrics.snapshot()["counters"]
         assert c["engine.decode_steps"] == calls * max_new
-        assert c["engine.host_reads"] == calls * max_new * (batch + 1)
+        assert c["engine.host_reads"] == calls * (max_new + 1)
         assert c["engine.decode_steps_kept"] == calls * (max_new - 1)
 
 
+@pytest.mark.parametrize("arch,kv,state", [
+    ("granite-3-2b", 2 * 2 * 4 * 96 * 2 * 16 * 2, 0),
+    # 2 attention layers; 2 mamba layers' conv windows (3 x 144 bf16) and
+    # SSM states (8 heads x 16 x 8 float32), per request
+    ("granite-4.0-h-micro", 2 * 2 * 4 * 96 * 2 * 16 * 2,
+     2 * 4 * (3 * 144 * 2 + 8 * 16 * 8 * 4)),
+])
+def test_engine_records_its_cache_bytes(arch, kv, state):
+    """As each batch's cache is built, the bytes it holds for keys and
+    values and for recurrent state."""
+    cfg, eng = _engine(arch)
+    eng.generate([[2, 3, 4]] * 4, max_new=1)
+    g = eng.metrics.snapshot()["gauges"]
+    assert g["engine.cache_bytes.kv"]["last"] == kv
+    assert g["engine.cache_bytes.state"]["last"] == state
+
+
 def test_engine_counters_stop_at_eos():
-    """A request that ended is no longer read; a batch whose requests all
-    ended stops before another decode_step."""
+    """A batch whose requests all ended stops before another
+    decode_step."""
     cfg, eng = _engine("granite-3-2b", temperature=0.0)
     prompt = [5, 9, 4]
     first = eng.generate([prompt], max_new=4)[0][len(prompt)]
@@ -152,6 +174,6 @@ def test_engine_counters_stop_at_eos():
                  ServeConfig(max_batch=4, max_len=96, eos_token=first))
     assert eng.generate([prompt], max_new=4) == [prompt + [first]]
     c = eng.metrics.snapshot()["counters"]
-    assert c["engine.host_reads"] == 1
+    assert c["engine.host_reads"] == 2
     assert c["engine.decode_steps"] == 0
     assert c["engine.decode_steps_kept"] == 0
