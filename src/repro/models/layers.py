@@ -3,12 +3,14 @@
 Everything here is config-driven and shape-polymorphic so one implementation
 serves all ten assigned architectures: RMSNorm, RoPE, GQA attention with an
 online-softmax KV-block scan (causal, sliding-window, logit softcap — no
-O(T^2) mask materialization), and (Sw/Ge)GLU MLPs.
+O(T^2) mask materialization) or, for prefill and training on a TPU, a fused
+Pallas flash kernel, and (Sw/Ge)GLU MLPs.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -91,14 +93,52 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               q_offset: jax.Array | int = 0,
               is_global: jax.Array | bool = True,
               kv_len: jax.Array | None = None) -> jax.Array:
-    """Online-softmax attention over KV blocks.
+    """Causal attention of q over k, v.
 
     q: (B, Tq, H, Dh); k, v: (B, Tk, K, Dh).  Causal with optional sliding
     window (disabled when ``is_global``) and logit soft-capping.  ``q_offset``
     is the absolute position of q[0] (decode: cache length so far).
     ``kv_len`` masks out cache positions >= kv_len.  Memory is O(Tq * block),
     never O(Tq * Tk) — required for 32k prefill and 500k decode.
+
+    Self-attention of several queries over their own keys from position 0
+    (training, prefill), with no traced window flag, a length that is a
+    multiple of 128 and one device, runs on a TPU as one fused Pallas flash
+    kernel (``kernels.ops.causal_flash_attention``) that visits only the
+    causally visible key blocks; everything else, and every platform but
+    the TPU, takes the online-softmax block scan.
     """
+    Tq, Tk = q.shape[1], k.shape[1]
+    if (1 < Tq == Tk and Tq % 128 == 0 and kv_len is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and (spec.window == 0 or is_global is True) and _one_device()):
+        from repro.kernels import ops
+        # the kernel's branch is lowered only for a TPU: never interpreted
+        kernel = functools.partial(
+            ops.causal_flash_attention,
+            scale=spec.scale or q.shape[-1] ** -0.5, softcap=spec.softcap,
+            interpret=False)
+        scan = functools.partial(_block_scan, spec=spec, q_offset=0,
+                                 is_global=True, kv_len=None)
+        return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=scan)
+    return _block_scan(q, k, v, spec=spec, q_offset=q_offset,
+                       is_global=is_global, kv_len=kv_len)
+
+
+def _one_device() -> bool:
+    """No mesh of several devices is active: the compiler does not
+    partition a Pallas kernel."""
+    from repro.sharding.context import current_mesh
+    mesh = current_mesh()
+    return mesh is None or mesh.size == 1
+
+
+def _block_scan(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                spec: AttnSpec, q_offset: jax.Array | int,
+                is_global: jax.Array | bool,
+                kv_len: jax.Array | None) -> jax.Array:
+    """Online-softmax attention over KV blocks, in float32 (the arguments
+    of :func:`attention`)."""
     B, Tq, H, Dh = q.shape
     _, Tk, K, _ = k.shape
     G = H // K
@@ -215,7 +255,8 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     * decode: ``kv_cache`` holds (B, S, K, Dh), or the stack of every
       layer's, (L, B, S, K, Dh), of which this is ``layer``; x is the new
       token(s); the cache is updated at ``cache_len`` and returned whole.
-      One new token reads it through :func:`decode_attention`.
+      One new token reads it through :func:`decode_attention`; a prompt
+      at a ``cache_len`` of a Python 0 attends to its own K/V alone.
     * cross-attention: ``xkv`` supplies the key/value source sequence.
     """
     src = x if xkv is None else xkv
@@ -253,6 +294,11 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
         if x.shape[1] == 1:
             out = decode_attention(q, lk, lv, spec, pos=pos,
                                    is_global=is_global)
+        elif isinstance(pos, int) and pos == 0:
+            # a prompt from position 0 sees only its own keys: attend to
+            # the fresh K/V, not to the cache's empty positions
+            out = attention(q, k.astype(ck.dtype), v.astype(cv.dtype), spec,
+                            is_global=is_global)
         else:
             out = attention(q, lk, lv, spec, q_offset=pos,
                             is_global=is_global, kv_len=pos + x.shape[1])
@@ -329,7 +375,12 @@ def remat_policy(name: str):
     if name == "none":
         return None
     if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        # and the flash kernel's output and log-sum-exp, so that the
+        # backward pass does not run the kernel's forward again
+        from repro.kernels.ops import FLASH_RESIDUALS
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(FLASH_RESIDUALS))
     if name == "full":
         return jax.checkpoint_policies.nothing_saveable
     raise ValueError(f"unknown remat policy {name!r}")

@@ -443,7 +443,8 @@ class Model:
         kinds = [k for k in ("mamba", "attention", "shared")
                  if any(kind == k for kind, _ in steps)]
         cached = cache is not None
-        pos = cache["pos"] if cached else None
+        # prefill starts at a Python 0: attention reads the prompt's own K/V
+        pos = 0 if cached else None
 
         def mamba(x, slots, i):
             conv, h, k, v = slots
@@ -555,14 +556,14 @@ class Model:
 
         if cfg.family in ("dense", "moe", "audio"):
             if "moe_blocks" in params:
-                x, cache = self._moe_grouped_pass(
-                    params, cache, x, positions, jnp.zeros((), jnp.int32))
+                x, cache = self._moe_grouped_pass(params, cache, x,
+                                                  positions, 0)
             else:
                 def layer(x, inp):
                     blk, is_global, kc, vc = inp
                     x, (nk, nv) = self._decoder_layer(
                         blk, x, positions, is_global, kv_cache=(kc, vc),
-                        cache_len=jnp.zeros((), jnp.int32))
+                        cache_len=0)
                     return x, (nk, nv)
 
                 layer = layers.maybe_remat(layer, cfg.remat_policy)

@@ -7,6 +7,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 
+import collections
 import re
 
 import jax
@@ -185,14 +186,15 @@ def _instructions(compiled) -> str:
                      if re.match(r"\s*(ROOT |ENTRY )?%", line))
 
 
-# sha256 of ``_instructions`` of granite-3-2b's programs as compiled before
-# the layer-pattern hybrid (granite-4.0-h) came into the model code, with
-# jax 0.9.0 and its TPU compiler: the hybrid changes no dense program
+# sha256 of ``_instructions`` of granite-3-2b's programs, with jax 0.9.0 and
+# its TPU compiler: ``decode_step`` as compiled before the layer-pattern
+# hybrid (granite-4.0-h) came into the model code, ``prefill`` since it
+# attends through the causal flash kernel
 DENSE_PROGRAMS = {
     "decode_step": "37d14987a704186952f85bc8c74a159d"
                    "71d08cd7f9467d1913275b1b771b1ff5",
-    "prefill": "595b9d6a1c36f4713f7395a1663c49ae"
-               "9641e4ca7c0deb90f899baeef8096e58",
+    "prefill": "e7970615d54c606e0795ead77385553c"
+               "abfd2bc0c7221cdef59eeed037fe7ee9",
 }
 
 
@@ -292,7 +294,74 @@ def test_hybrid_programs_keep_layer_scopes(hybrid_decode, one_chip,
             "unembed"} <= kinds
 
 
-def test_train_step_compiles_for_one_chip(topo):
+def _kernel_calls(compiled) -> dict[tuple[str, str], int]:
+    """(kernel name, layer kind of its ``op_name``) -> how many times a run
+    of the program calls it: a call inside a loop's body counts once per
+    trip (the trip count is the constant its condition compares with)."""
+    text = compiled.as_text()
+    comps: dict[str, list[str]] = {}
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name and line.startswith(" "):
+            comps[name].append(line)
+        elif name and comps[name] and line not in ("", "}"):
+            # a kernel's metadata breaks its instruction over lines
+            comps[name][-1] += line
+
+    def trips(cond: str) -> int:
+        return max(int(n) for line in comps[cond]
+                   for n in re.findall(r"s32\[\]\S* constant\((\d+)\)",
+                                       line))
+
+    def calls(comp: str) -> collections.Counter:
+        out = collections.Counter()
+        for line in comps[comp]:
+            kernel = re.match(r"\s*%(splash_\w+?)(?:\.\d+)? = .*"
+                              r"custom-call\(", line)
+            if kernel:
+                op = re.search(r'op_name="([^"]*)"', line)
+                out[kernel.group(1), layer_trace.kind_of(op.group(1))] += 1
+            loop = re.search(r"condition=(%[\w.\-]+), body=(%[\w.\-]+)",
+                             line)
+            if loop:
+                for k, n in calls(loop.group(2)).items():
+                    out[k] += n * trips(loop.group(1))
+        return out
+
+    entry = re.search(r"^ENTRY (%[\w.\-]+) ", text, re.M).group(1)
+    return dict(calls(entry))
+
+
+def test_prefill_runs_the_flash_kernel_per_layer(serving):
+    """Prefill from position 0 attends through the fused flash kernel:
+    one forward call per layer, each under the ``attention`` scope, and
+    no other kernel."""
+    assert _kernel_calls(serving["prefill"]) == {
+        ("splash_mqa_fwd_no_residuals", "attention"): 40}
+
+
+def test_long_prompt_prefill_fits(one_chip):
+    """glm4-9b's serve-prefill shapes, cut to 2 layers: the longest
+    prompt, 6144 tokens, over an 8192-token cache, through the kernel."""
+    model = model_lib.build(registry.get("glm4-9b").with_depth(2))
+    params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
+                     one_chip)
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(1, 8192)),
+                    one_chip)
+    tokens = jax.ShapeDtypeStruct((1, 6144), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.prefill).lower(params, cache, tokens,
+                                            None).compile()
+    _fits(compiled)
+    assert _kernel_calls(compiled) == {
+        ("splash_mqa_fwd_no_residuals", "attention"): 2}
+
+
+@pytest.fixture(scope="module")
+def train_step(topo):
     """The train launcher's donated step at published widths, cut to 2
     layers and a small batch so that it compiles in seconds."""
     cfg = registry.get("granite-3-2b").with_depth(2)
@@ -308,9 +377,21 @@ def test_train_step_compiles_for_one_chip(topo):
     batch = {"tokens": jax.ShapeDtypeStruct(
         (2, 512), jnp.int32, sharding=NamedSharding(mesh, P()))}
     with use_mesh(mesh):
-        compiled = jax.jit(ts.make_train_step(model, opt),
-                           out_shardings=(shardings, None),
-                           donate_argnums=(0,)).lower(state, batch).compile()
-    _fits(compiled)
+        return jax.jit(ts.make_train_step(model, opt),
+                       out_shardings=(shardings, None),
+                       donate_argnums=(0,)).lower(state, batch).compile()
+
+
+def test_train_step_compiles_for_one_chip(train_step):
+    _fits(train_step)
     # the donated state is reused in place for the new state
-    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    assert train_step.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_train_step_runs_the_flash_kernel_both_ways(train_step):
+    """Per layer, the kernel's forward (saving its output and log-sum-exp,
+    which the remat policy keeps, so the backward pass does not run it
+    again) and its fused backward, all under the ``attention`` scope."""
+    assert _kernel_calls(train_step) == {
+        ("splash_mqa_fwd_residuals", "attention"): 2,
+        ("splash_mqa_dkv_no_residuals", "attention"): 2}
