@@ -270,24 +270,28 @@ def test_hybrid_decode_updates_its_state_in_place(hybrid_decode):
     assert len(big) == len(state) + len(kv), big
 
 
+@pytest.fixture(scope="module")
+def hybrid_prefill(one_chip):
+    """granite-4.0-h-micro's prefill at a small size: 10 layers, 2
+    prompts of 256 tokens over a 512-token cache."""
+    model = model_lib.build(
+        registry.get("granite-4.0-h-micro").with_depth(10))
+    params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
+                     one_chip)
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(2, 512)),
+                    one_chip)
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    return jax.jit(model.prefill).lower(params, cache, tokens,
+                                        None).compile()
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill"])
-def test_hybrid_programs_keep_layer_scopes(hybrid_decode, one_chip,
+def test_hybrid_programs_keep_layer_scopes(hybrid_decode, hybrid_prefill,
                                            program):
     """The mamba layers' work is named ``ssm``, beside the kinds the dense
     programs show; prefill at a small size, the scopes are the same."""
-    if program == "decode_step":
-        compiled = hybrid_decode[0]
-    else:
-        cfg = registry.get("granite-4.0-h-micro")
-        model = model_lib.build(cfg.with_depth(10))
-        params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
-                         one_chip)
-        cache = _placed(jax.eval_shape(lambda: model.init_cache(2, 512)),
-                        one_chip)
-        tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32,
-                                      sharding=one_chip)
-        compiled = jax.jit(model.prefill).lower(params, cache, tokens,
-                                                None).compile()
+    compiled = (hybrid_decode[0] if program == "decode_step"
+                else hybrid_prefill)
     names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
     kinds = {layer_trace.kind_of(n) for n in names}
     assert {"embed", "ssm", "attention", "kv_cache", "mlp",
@@ -395,3 +399,29 @@ def test_train_step_runs_the_flash_kernel_both_ways(train_step):
     assert _kernel_calls(train_step) == {
         ("splash_mqa_fwd_residuals", "attention"): 2,
         ("splash_mqa_dkv_no_residuals", "attention"): 2}
+
+
+# sha256 of ``_instructions`` of the other pinned programs, with jax 0.9.0
+# and its TPU compiler, as compiled before one schedule walked every
+# family's layer stack: granite-4.0-h-micro's decode step at the
+# serve-chat-b32 shapes and its prefill at ``hybrid_prefill``'s, and
+# granite-3-2b's 2-layer train step
+PINNED_PROGRAMS = {
+    "hybrid_decode": "2236280cf099ef5af91fedf445b9f576"
+                     "36a306cba8852319006ae007e5077d0e",
+    "hybrid_prefill": "7d1dddcf4be2c94cf941e15457b221ea"
+                      "0f11524b34b8498c183e97a1f2a42d14",
+    "train_step": "28729543994ab0f09c7bf5bd60787207"
+                  "9b67ff9feb33e019d60a6fdfcbe40366",
+}
+
+
+@pytest.mark.parametrize("program", sorted(PINNED_PROGRAMS))
+def test_pinned_programs_are_unchanged(request, program):
+    import hashlib
+
+    compiled = request.getfixturevalue(program)
+    if program == "hybrid_decode":
+        compiled = compiled[0]
+    digest = hashlib.sha256(_instructions(compiled).encode()).hexdigest()
+    assert digest == PINNED_PROGRAMS[program]
