@@ -81,8 +81,6 @@ class ModelConfig:
     overlap: str = "none"                  # none | shared_bus (paper technique)
     constrain_activations: bool = False    # pin residual stream to pure-DP
     #   sharding at layer boundaries (weights gather; activations stay put)
-    constrain_internals: bool = False      # additionally pin qkv + mlp hidden
-    #   activations (kills partial-sum all-reduces; §Perf iteration 5)
     unroll_layers: bool = False            # dry-run cost probes: XLA counts
     #   scan bodies once, so probes compile fully unrolled (dryrun.py)
 

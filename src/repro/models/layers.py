@@ -247,7 +247,6 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
                layer: jax.Array | int | None = None,
                xkv: jax.Array | None = None,
                use_rope: bool = True,
-               constrain_dp: bool = False,
                ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Projections + (cached) attention.  Returns (out, (k_all, v_all)).
 
@@ -263,13 +262,6 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", src, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", src, params["wv"])
-    if constrain_dp:
-        # DP-stationary projections: force weight gathers over the fsdp
-        # axis rather than partial-sum all-reduces of activations
-        from repro.sharding.context import constrain
-        q = constrain(q, ("pod", "data"), None, None, None)
-        k = constrain(k, ("pod", "data"), None, None, None)
-        v = constrain(v, ("pod", "data"), None, None, None)
     if "q_norm" in params:
         q = rms_norm(q, params["q_norm"], 1e-6)
         k = rms_norm(k, params["k_norm"], 1e-6)
@@ -331,8 +323,7 @@ def _act(x: jax.Array, kind: str) -> jax.Array:
 
 @jax.named_scope(MLP)
 def mlp_block(params: Params, x: jax.Array, act: str,
-              overlap: bool = False, constrain_dp: bool = False
-              ) -> jax.Array:
+              overlap: bool = False) -> jax.Array:
     """(Sw/Ge)GLU FFN.
 
     With ``overlap=True`` (config.overlap == "shared_bus") and an active
@@ -355,12 +346,6 @@ def mlp_block(params: Params, x: jax.Array, act: str,
                 lambda v: _act(v, act))
     g = _act(jnp.einsum("btd,df->btf", x, params["wi_gate"]), act)
     u = jnp.einsum("btd,df->btf", x, params["wi_up"])
-    if constrain_dp:
-        # pin hidden activations to pure-DP: XLA must gather the (small)
-        # weights instead of all-reducing (large) partial activation sums
-        from repro.sharding.context import constrain
-        g = constrain(g, ("pod", "data"), None, None)
-        u = constrain(u, ("pod", "data"), None, None)
     return jnp.einsum("btf,fd->btd", g * u, params["wo"])
 
 

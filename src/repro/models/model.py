@@ -1,29 +1,35 @@
-"""Config-driven model assembly for all ten assigned architectures.
+"""Config-driven model assembly for every registry architecture.
 
 ``build(cfg)`` returns a ``Model`` with:
 
-* ``init(key)``                      -> params pytree (stacked layers for scan)
-* ``forward(params, batch)``         -> logits (training / prefill path)
+* ``init(key)``                      -> params pytree (stacked layer weights)
+* ``forward(params, batch)``         -> logits (training path)
 * ``train_loss(params, batch)``      -> scalar LM loss
-* ``init_cache(B)``                  -> decode cache pytree (KV / SSM states)
+* ``init_cache(B, max_len)``         -> decode cache pytree (KV / SSM states)
+* ``prefill(params, cache, tokens)`` -> (last logits, cache)
 * ``decode_step(params, cache, tok)``-> (logits, cache)  [one-token serve step]
 
-Layer stacks are scanned (``jax.lax.scan`` over stacked params) so the HLO
-stays compact for the 512-device dry-run; heterogeneous schedules (gemma
-local/global, zamba2 shared attention, llama-vision cross blocks) are
-expressed as scanned per-layer flags or group-structured scans.  The
-unrolled towers are the ``decode_step`` of the decoder stack and of the
-hybrids: there each layer updates and reads its part of the stacked cache
-(KV, or a mamba layer's conv window and SSM state) in place, at a constant
-index.  The hybrids' other passes scan their schedule of layer kinds
-(granite-4.0-h's ``layer_types``, zamba2's shared blocks) with a
-``lax.switch`` per step.
+Every family's layer stack is one schedule (``Model._schedule``): its
+layers in order, each a step of a kind (attention with its MLP or MoE, a
+mamba mixer, a cross-attention block), with the weight stack and index it
+takes its weights from and its slot in its kind's stacked cache.  ``init``
+and ``init_cache`` size the stacks from it, and two walks run it:
+
+* ``decode_step`` is unrolled: each step is one call of a jitted function
+  per kind, which reads and writes its own slot of the stacked cache at an
+  index the compiler sees as a constant, in place.
+* Prefill and training scan the schedule, which keeps those programs and
+  their compile times compact.  A stack of one kind scans its weights and
+  cache slots as ``xs``; a stack of several kinds carries the cache stacks
+  and runs each step's kind through a ``lax.switch``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Any
+import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +37,23 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers, moe, ssm
 from repro.models.layers import AttnSpec, Params
+
+# each kind's stacked cache, one slot per step of that kind
+_CACHE_SLOTS = {"attention": ("k", "v"), "mamba": ("conv", "h"),
+               "cross": ("media_k", "media_v")}
+
+# which of ``init``'s keys draws each weight stack
+_INIT_KEYS = {"blocks": 2, "attn_blocks": 3, "shared_attn": 3,
+              "cross_blocks": 3, "moe_blocks": 5}
+
+
+class Step(NamedTuple):
+    """One layer of the schedule."""
+    kind: str          # "attention" (and its MLP or MoE), "mamba", "cross"
+    stack: str         # the params entry that holds its weights
+    index: int         # its weights' index in that stack
+    slot: int          # its index in its kind's cache stacks
+    is_global: bool    # attention: not held to the sliding window
 
 
 def _stack_init(fn, key, n: int):
@@ -43,9 +66,86 @@ def _take(tree, i):
         x, i, keepdims=False), tree)
 
 
+@contextlib.contextmanager
+def _cache_scope(kind: str):
+    """The scopes of a kind's cache writes."""
+    if kind == "mamba":
+        with jax.named_scope(layers.SSM):
+            yield
+    else:
+        with jax.named_scope(layers.ATTENTION), \
+                jax.named_scope(layers.KV_CACHE):
+            yield
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     cfg: ModelConfig
+
+    # ---------------- the layer schedule ----------------
+
+    def _schedule(self) -> tuple[Step, ...]:
+        """The stack in order, one step per layer:
+
+        * attention over ``blocks`` (dense, audio, MoE, the VLM's self
+          attention); llama4 takes every ``moe_every``-th from
+          ``moe_blocks``, and its K/V slots follow the dense layers';
+        * mamba over ``blocks`` (falcon-mamba, zamba2), and for
+          granite-4.0-h as ``layer_types`` say, its attention layers over
+          ``attn_blocks``;
+        * zamba2: after every ``attn_every``-th layer, a shared attention
+          block (``shared_attn``, its weight sets cycled);
+        * the VLM: after every ``cross_attn_every``-th layer, a cross step
+          into its slot of the media K/V.
+        """
+        cfg = self.cfg
+        every = cfg.local_global_every
+        order = []                              # (kind, stack, is_global)
+        for i in range(cfg.n_layers):
+            if cfg.layer_types:
+                kind = cfg.layer_types[i]
+            else:
+                kind = ("mamba" if cfg.family in ("ssm", "hybrid")
+                        else "attention")
+            stack = "blocks"
+            if kind == "attention" and cfg.layer_types:
+                stack = "attn_blocks"
+            elif kind == "attention" and cfg.moe_every > 1 and (
+                    i % cfg.moe_every == cfg.moe_every - 1):
+                stack = "moe_blocks"
+            order.append((kind, stack, not (cfg.sliding_window and every)
+                          or i % every == every - 1))
+            if cfg.attn_every and i % cfg.attn_every == cfg.attn_every - 1:
+                order.append(("attention", "shared_attn", True))
+            if cfg.cross_attn_every and (
+                    i % cfg.cross_attn_every == cfg.cross_attn_every - 1):
+                order.append(("cross", "cross_blocks", True))
+        # a kind's cache slots: its stacks one after another, in the order
+        # of their first layers
+        count, first, taken = {}, {}, {}
+        for kind, stack, _ in order:
+            count[stack] = count.get(stack, 0) + 1
+        for kind, stack, _ in order:
+            if stack not in first:
+                first[stack] = taken.get(kind, 0)
+                taken[kind] = first[stack] + count[stack]
+        seen = dict.fromkeys(count, 0)
+        steps = []
+        for kind, stack, is_global in order:
+            n = seen[stack]
+            seen[stack] += 1
+            index = (n % cfg.n_shared_attn_blocks if stack == "shared_attn"
+                     else n)
+            steps.append(Step(kind, stack, index, first[stack] + n,
+                              is_global))
+        return tuple(steps)
+
+    def _weights(self, params: Params, stack: str, i) -> Params:
+        """One step's weights: entry ``i`` (may be traced) of ``stack``."""
+        blk = _take(params[stack], i)
+        if stack == "shared_attn":
+            blk = {**blk, "ln1": blk["ln"]}
+        return blk
 
     # ---------------- parameter init ----------------
 
@@ -62,52 +162,32 @@ class Model:
         if not cfg.tie_embeddings:
             p["unembed"] = layers.dense_init(keys[1], cfg.d_model,
                                              (cfg.vocab_size,), dtype)
-        if cfg.family in ("dense", "moe", "audio", "vlm"):
-            if cfg.family == "moe" and cfg.moe_every > 1:
-                n_moe = cfg.n_layers // cfg.moe_every
-                p["blocks"] = _stack_init(
-                    lambda k: self._init_block(k, dtype, kind="dense"),
-                    keys[2], cfg.n_layers - n_moe)
-                p["moe_blocks"] = _stack_init(
-                    lambda k: self._init_block(k, dtype, kind="moe"),
-                    keys[5], n_moe)
-            else:
-                p["blocks"] = _stack_init(
-                    lambda k: self._init_block(k, dtype), keys[2],
-                    cfg.n_layers)
-        if cfg.family == "vlm":
-            n_cross = cfg.n_layers // cfg.cross_attn_every
-            p["cross_blocks"] = _stack_init(
-                lambda k: self._init_cross_block(k, dtype), keys[3], n_cross)
+        kinds, sizes = {}, {}
+        for step in self._schedule():
+            kinds[step.stack] = step.kind
+            sizes[step.stack] = sizes.get(step.stack, 0) + 1
+        if "shared_attn" in sizes:
+            sizes["shared_attn"] = cfg.n_shared_attn_blocks
+        for stack, n in sizes.items():
+            p[stack] = _stack_init(functools.partial(
+                self._init_layer, kinds[stack], stack, dtype=dtype),
+                keys[_INIT_KEYS[stack]], n)
+        if cfg.media_embed_dim:
             p["media_proj"] = layers.dense_init(
                 keys[4], cfg.media_embed_dim, (cfg.d_model,), dtype)
-        if cfg.family == "audio":
-            p["media_proj"] = layers.dense_init(
-                keys[4], cfg.media_embed_dim, (cfg.d_model,), dtype)
-        if cfg.family == "ssm":
-            p["blocks"] = _stack_init(
-                lambda k: self._init_ssm_block(k, dtype), keys[2],
-                cfg.n_layers)
-        if cfg.family == "hybrid" and cfg.layer_types:
-            # mamba layers and attention layers, each kind stacked in
-            # layer order; each carries its MLP
-            n_attn = cfg.layer_types.count("attention")
-            p["blocks"] = _stack_init(
-                lambda k: self._init_ssm_block(k, dtype), keys[2],
-                cfg.n_layers - n_attn)
-            p["attn_blocks"] = _stack_init(
-                lambda k: self._init_block(k, dtype), keys[3], n_attn)
-        elif cfg.family == "hybrid":
-            p["blocks"] = _stack_init(
-                lambda k: self._init_ssm_block(k, dtype), keys[2],
-                cfg.n_layers)
-            p["shared_attn"] = _stack_init(
-                lambda k: self._init_shared_attn(k, dtype), keys[3],
-                cfg.n_shared_attn_blocks)
         return p
 
-    # per-family sub-inits -------------------------------------------------
-
+    def _init_layer(self, kind: str, stack: str, key, dtype) -> Params:
+        cfg = self.cfg
+        if kind == "mamba":
+            return self._init_ssm_block(key, dtype)
+        if kind == "cross":
+            return self._init_cross_block(key, dtype)
+        if stack == "shared_attn":
+            return self._init_shared_attn(key, dtype)
+        moe_mlp = stack == "moe_blocks" or (cfg.n_experts > 0
+                                            and cfg.moe_every == 1)
+        return self._init_block(key, dtype, moe_mlp)
 
     def _scan(self, f, init, xs):
         """lax.scan over stacked layers; fully unrolled when the config asks
@@ -123,10 +203,8 @@ class Model:
                         kv_block=cfg.attn_kv_block,
                         scale=cfg.attention_multiplier)
 
-    def _init_block(self, key, dtype, kind: str | None = None) -> Params:
+    def _init_block(self, key, dtype, moe_mlp: bool) -> Params:
         cfg = self.cfg
-        if kind is None:
-            kind = "moe" if cfg.family == "moe" else "dense"
         ks = jax.random.split(key, 4)
         p = {
             "ln1": jnp.zeros((cfg.d_model,), dtype),
@@ -135,7 +213,7 @@ class Model:
                                             qk_norm=cfg.qk_norm),
             "ln2": jnp.zeros((cfg.d_model,), dtype),
         }
-        if kind == "moe":
+        if moe_mlp:
             p["moe"] = moe.init_moe_params(ks[1], cfg.d_model, cfg, dtype)
         else:
             p["mlp"] = layers.init_mlp_params(ks[1], cfg.d_model, cfg.d_ff,
@@ -177,17 +255,7 @@ class Model:
                                               dtype)
         return p
 
-    # ---------------- per-layer flags ----------------
-
-    def _layer_is_global(self) -> jax.Array:
-        cfg = self.cfg
-        if cfg.sliding_window and cfg.local_global_every:
-            idx = jnp.arange(cfg.n_layers)
-            return (idx % cfg.local_global_every) == (
-                cfg.local_global_every - 1)
-        return jnp.ones((cfg.n_layers,), bool)
-
-    # ---------------- forward (train / prefill) ----------------
+    # ---------------- embedding and unembedding ----------------
 
     def _scale_embedding(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -203,35 +271,20 @@ class Model:
         m = self.cfg.residual_multiplier
         return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
 
+    def _media_tokens(self, params: Params, media: jax.Array,
+                      dtype) -> jax.Array:
+        return jnp.einsum("bmd,dk->bmk", media.astype(dtype),
+                          params["media_proj"])
+
     @jax.named_scope(layers.EMBED)
     def embed_inputs(self, params: Params, batch: dict) -> jax.Array:
+        """Token embeddings; audio's conditioning frames go in front."""
         cfg = self.cfg
         x = self._scale_embedding(params["embed"][batch["tokens"]])
         if cfg.family == "audio":
-            media = jnp.einsum("bmd,dk->bmk", batch["media"].astype(x.dtype),
-                               params["media_proj"])
+            media = self._media_tokens(params, batch["media"], x.dtype)
             x = jnp.concatenate([media, x], axis=1)
         return x
-
-    def forward(self, params: Params, batch: dict) -> jax.Array:
-        cfg = self.cfg
-        x = self.embed_inputs(params, batch)
-        B, T, _ = x.shape
-        positions = jnp.arange(T)[None, :].repeat(B, 0)
-        if cfg.family in ("dense", "moe", "audio"):
-            x = self._run_decoder(params, x, positions)
-        elif cfg.family == "vlm":
-            x = self._run_vlm(params, x, positions,
-                              batch["media"])
-        elif cfg.family == "ssm":
-            x = self._run_ssm(params, x)
-        elif cfg.family == "hybrid":
-            x, _ = self._hybrid_scan(params, None, x, positions)
-        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        if cfg.family == "audio":
-            x = x[:, cfg.n_media_tokens:]           # strip conditioning frames
-        logits = self._unembed(params, x)
-        return logits
 
     @jax.named_scope(layers.UNEMBED)
     def _unembed(self, params: Params, x: jax.Array) -> jax.Array:
@@ -244,6 +297,8 @@ class Model:
             logits = (cfg.final_logit_softcap
                       * jnp.tanh(logits / cfg.final_logit_softcap))
         return logits
+
+    # ---------------- the layers of each kind ----------------
 
     def _constrain_residual(self, x):
         """Optionally pin the residual stream to pure-DP sharding at layer
@@ -265,85 +320,15 @@ class Model:
             norm_eps=cfg.norm_eps, positions=positions, is_global=is_global,
             kv_cache=kv_cache, cache_len=cache_len, layer=layer,
             use_rope=cfg.family != "audio"
-            and cfg.position_embedding == "rope",
-            constrain_dp=cfg.constrain_internals)
+            and cfg.position_embedding == "rope")
         x = x + self._residual(a)
         h = layers.rms_norm(x, blk["ln2"], cfg.norm_eps)
         if "moe" in blk:
             x = x + moe.moe_block(blk["moe"], h, cfg)
         else:
             x = x + self._residual(layers.mlp_block(
-                blk["mlp"], h, cfg.act, overlap=cfg.overlap == "shared_bus",
-                constrain_dp=cfg.constrain_internals))
+                blk["mlp"], h, cfg.act, overlap=cfg.overlap == "shared_bus"))
         return x, kv
-
-    def _run_decoder(self, params, x, positions):
-        cfg = self.cfg
-        flags = self._layer_is_global()
-
-        if "moe_blocks" in params:
-            # llama4-style interleave: groups of (moe_every-1 dense + 1 moe)
-            k = cfg.moe_every - 1
-            n_groups = cfg.n_layers // cfg.moe_every
-            dense = jax.tree.map(
-                lambda a: a.reshape(n_groups, k, *a.shape[1:]),
-                params["blocks"])
-
-            def group(x, inp):
-                dgrp, mblk = inp
-
-                def inner(x, blk):
-                    x, _ = self._decoder_layer(blk, x, positions, True)
-                    return x, None
-
-                x, _ = self._scan(inner, x, dgrp)
-                x, _ = self._decoder_layer(mblk, x, positions, True)
-                return x, None
-
-            group = layers.maybe_remat(group, cfg.remat_policy)
-            x, _ = self._scan(group, x, (dense, params["moe_blocks"]))
-            return x
-
-        def layer(x, inp):
-            blk, is_global = inp
-            x, _ = self._decoder_layer(blk, x, positions, is_global)
-            return x, None
-
-        layer = layers.maybe_remat(layer, cfg.remat_policy)
-        x, _ = self._scan(layer, x, (params["blocks"], flags))
-        return x
-
-    def _run_vlm(self, params, x, positions, media):
-        cfg = self.cfg
-        mtok = jnp.einsum("bmd,dk->bmk", media.astype(x.dtype),
-                          params["media_proj"])
-        k = cfg.cross_attn_every
-        n_groups = cfg.n_layers // k
-        blocks = jax.tree.map(
-            lambda a: a.reshape(n_groups, k, *a.shape[1:]), params["blocks"])
-        flags = self._layer_is_global().reshape(n_groups, k)
-
-        def group(x, inp):
-            grp, cross, fl = inp
-
-            def self_layer(x, inner):
-                blk, g = inner
-                x, _ = self._decoder_layer(blk, x, positions, g)
-                return x, None
-
-            x, _ = self._scan(self_layer, x, (grp, fl))
-            # gated cross-attention into the (stub) vision tokens
-            h = layers.rms_norm(x, cross["ln"], cfg.norm_eps)
-            a, _ = layers.attn_block(
-                cross["attn"], h, self._attn_spec(),
-                rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                positions=positions, xkv=mtok, use_rope=False)
-            x = x + jnp.tanh(cross["gate"]).astype(x.dtype) * a
-            return x, None
-
-        group = layers.maybe_remat(group, cfg.remat_policy)
-        x, _ = self._scan(group, x, (blocks, params["cross_blocks"], flags))
-        return x
 
     @jax.named_scope(layers.SSM)
     def _ssm_layer(self, blk, x, state=None, mask=None):
@@ -357,15 +342,6 @@ class Model:
                                             state=state, mask=mask)
         return x + self._residual(y), new_state
 
-    def _run_ssm(self, params, x):
-        def layer(x, blk):
-            x, _ = self._ssm_layer(blk, x)
-            return x, None
-
-        layer = layers.maybe_remat(layer, self.cfg.remat_policy)
-        x, _ = self._scan(layer, x, params["blocks"])
-        return x
-
     def _mamba_layer(self, blk, x, state=None, mask=None):
         """One mamba layer, and for granite-4.0-h the MLP after it.
         ``state``: its (conv window, SSM state), or None (no cache)."""
@@ -377,147 +353,157 @@ class Model:
                 blk["mlp"], hn, cfg.act, overlap=cfg.overlap == "shared_bus"))
         return x, state
 
-    def _mamba_decode_layer(self, blk, x, conv, h, layer):
-        """``_mamba_layer`` over every mamba layer's stacked conv windows
-        and SSM states, of which this layer's are at ``layer``: read there
-        and written back there, in place where the compiler sees a
-        constant index."""
+    def _cross_layer(self, blk, x, mk, mv):
+        """Gated cross-attention of x into the media's K/V (B, M, K, Dh)."""
+        h = layers.rms_norm(x, blk["ln"], self.cfg.norm_eps)
+        with jax.named_scope(layers.ATTENTION):
+            q = jnp.einsum("btd,dhk->bthk", h, blk["attn"]["wq"])
+            out = layers.attention(q, mk, mv, self._attn_spec(),
+                                   q_offset=mk.shape[1], is_global=True)
+            a = jnp.einsum("bthk,hkd->btd", out, blk["attn"]["wo"])
+        return x + jnp.tanh(blk["gate"]).astype(x.dtype) * a
+
+    # one decode step of each kind, over its kind's stacked cache, of which
+    # the step's slot is at ``layer``: read there and written back there,
+    # in place where the compiler sees a constant index
+
+    def _attn_decode_layer(self, blk, x, kv, layer, positions, pos,
+                           is_global):
+        return self._decoder_layer(blk, x, positions, is_global,
+                                   kv_cache=kv, cache_len=pos, layer=layer)
+
+    def _mamba_decode_layer(self, blk, x, state, layer, *_):
+        conv, h = state
         x, (nc, nh) = self._mamba_layer(blk, x, (conv[layer], h[layer]))
         with jax.named_scope(layers.SSM):
             conv = jax.lax.dynamic_update_index_in_dim(
                 conv, nc.astype(conv.dtype), layer, 0)
             h = jax.lax.dynamic_update_index_in_dim(h, nh, layer, 0)
-        return x, conv, h
+        return x, (conv, h)
 
-    def _attn_decode_layer(self, blk, x, positions, k, v, pos, layer):
-        """One attention layer over the stacked K/V caches at ``layer``."""
-        x, (k, v) = self._decoder_layer(blk, x, positions, True,
-                                        kv_cache=(k, v), cache_len=pos,
-                                        layer=layer)
-        return x, k, v
+    def _cross_decode_layer(self, blk, x, media_kv, layer, *_):
+        mk, mv = media_kv
+        return self._cross_layer(blk, x, mk[layer], mv[layer]), media_kv
 
-    def _hybrid_schedule(self) -> list[tuple[str, int]]:
-        """The hybrid stack in order: (kind, index in its weight stack).
-        granite-4.0-h: each layer's mixer, "mamba" or "attention";
-        zamba2: every mamba layer, and after each ``attn_every``-th one
-        a "shared" block (its cache index is its place among them)."""
-        cfg = self.cfg
-        if cfg.layer_types:
-            seen = {"mamba": 0, "attention": 0}
-            out = []
-            for kind in cfg.layer_types:
-                out.append((kind, seen[kind]))
-                seen[kind] += 1
-            return out
-        out = []
-        for i in range(cfg.n_layers):
-            out.append(("mamba", i))
-            if i % cfg.attn_every == cfg.attn_every - 1:
-                out.append(("shared", i // cfg.attn_every))
-        return out
+    # ---------------- forward (training) and prefill ----------------
 
-    def _hybrid_block(self, params, kind, i):
-        """The weights of one step of the hybrid schedule (``i`` may be
-        traced)."""
-        if kind == "mamba":
-            return _take(params["blocks"], i)
-        if kind == "attention":
-            return _take(params["attn_blocks"], i)
-        # zamba2's shared block, cycled over its weight sets
-        sa = _take(params["shared_attn"], i % self.cfg.n_shared_attn_blocks)
-        return {**sa, "ln1": sa["ln"]}
+    def _walk(self, params, x, positions, cache=None, media=None,
+              mask=None):
+        """Prefill (with a cache) or training (without) over the schedule,
+        as a scan.  Returns (x, cache); without a cache nothing is cached.
 
-    def _hybrid_scan(self, params, cache, x, positions, mask=None):
-        """Prefill and training over a hybrid stack: one scan over the
-        schedule, each step the layer of its kind (``lax.switch``) with its
-        weights at the step's index.  With a cache, a step reads its
-        layer's slots (a mamba layer's conv window and SSM state, an
-        attention layer's K/V) out of the stacked caches, the switch
-        computes on those slots alone, and the step writes them back in
-        place; a step of the other kind writes back what it read.  So the
+        A stack of one kind scans its weights and each layer's cache slots
+        as ``xs``.  A stack of several kinds runs each step's kind through
+        a ``lax.switch``, with its weights at the step's index.  With a
+        cache, a step reads its slots (and, for the other kinds, the slots
+        last used) out of the stacked caches, the switch computes on those
+        slots alone, and the step writes them back in place; so the
         stacks, carried by the scan, never enter a branch, where the one
-        that leaves a stack unchanged would copy it whole.  Returns
-        (x, cache); without a cache nothing is cached."""
+        that leaves a stack unchanged would copy it whole."""
         cfg = self.cfg
-        steps = self._hybrid_schedule()
-        kinds = [k for k in ("mamba", "attention", "shared")
-                 if any(kind == k for kind, _ in steps)]
+        steps = self._schedule()
         cached = cache is not None
         # prefill starts at a Python 0: attention reads the prompt's own K/V
         pos = 0 if cached else None
+        if any(step.kind == "cross" for step in steps):
+            media = self._media_tokens(params, media, x.dtype)
 
-        def mamba(x, slots, i):
-            conv, h, k, v = slots
-            blk = self._hybrid_block(params, "mamba", i)
-            x, state = self._mamba_layer(blk, x, (conv, h) if cached
-                                         else None, mask)
-            return x, (*state, k, v) if cached else slots
+        def attention(blk, x, is_global, slots):
+            x, kv = self._decoder_layer(blk, x, positions, is_global,
+                                        kv_cache=slots, cache_len=pos)
+            return x, kv if cached else None
 
-        def attention(kind):
-            def run(x, slots, i):
-                conv, h, k, v = slots
-                x, kv = self._decoder_layer(
-                    self._hybrid_block(params, kind, i), x, positions, True,
-                    kv_cache=(k, v) if cached else None, cache_len=pos)
-                return x, (conv, h, *kv) if cached else slots
-            return run
+        def mamba(blk, x, is_global, slots):
+            x, state = self._mamba_layer(blk, x, slots, mask)
+            return x, state if cached else None
 
-        branches = [mamba if k == "mamba" else attention(k) for k in kinds]
+        def cross(blk, x, is_global, slots):
+            with jax.named_scope(layers.ATTENTION):
+                kv = tuple(jnp.einsum("bmd,dhk->bmhk", media, blk["attn"][w])
+                           for w in ("wk", "wv"))
+            if cached:
+                kv = tuple(a.astype(s.dtype) for a, s in zip(kv, slots))
+            return self._cross_layer(blk, x, *kv), kv if cached else None
+
+        run = {"attention": attention, "mamba": mamba, "cross": cross}
+        stacks = list(dict.fromkeys((step.kind, step.stack)
+                                    for step in steps))
+        if len(stacks) == 1:
+            # the steps are the stack's entries in order, each its own slot
+            (kind, stack), = stacks
+            names = _CACHE_SLOTS[kind]
+
+            def layer(x, inp):
+                blk, is_global, slots = inp
+                return run[kind](blk, x, is_global, slots)
+
+            flags = (jnp.asarray([step.is_global for step in steps])
+                     if kind == "attention" else None)
+            layer = layers.maybe_remat(layer, cfg.remat_policy)
+            x, slots = self._scan(layer, x, (
+                params[stack], flags,
+                tuple(cache[n] for n in names) if cached else None))
+            if cached:
+                cache = {**cache, **dict(zip(names, slots))}
+            return x, cache
+
+        kinds = list(dict.fromkeys(step.kind for step in steps))
+        names = [n for kind in kinds for n in _CACHE_SLOTS[kind]]
+
+        def branch(kind, stack):
+            lo = 2 * kinds.index(kind)         # its slots among all slots
+
+            def step(x, slots, i, is_global):
+                x, own = run[kind](self._weights(params, stack, i), x,
+                                   is_global,
+                                   slots[lo:lo + 2] if cached else None)
+                return x, (slots[:lo] + own + slots[lo + 2:] if cached
+                           else slots)
+            return step
+
+        branches = [branch(*s) for s in stacks]
 
         def layer(carry, step):
-            x, stacks = carry
-            kind, i, at = step                     # at: (mamba, KV) slot
-            slots = ((stacks[0][at[0]], stacks[1][at[0]], stacks[2][at[1]],
-                      stacks[3][at[1]]) if cached else (None,) * 4)
-            x, slots = jax.lax.switch(kind, branches, x, slots, i)
+            x, caches = carry
+            b, i, at, is_global = step          # at: a slot per kind
+            slots = (tuple(c[at[j // 2]] for j, c in enumerate(caches))
+                     if cached else (None,) * len(names))
+            x, slots = jax.lax.switch(b, branches, x, slots, i, is_global)
             if cached:
-                conv, h, k, v = stacks
-                with jax.named_scope(layers.SSM):
-                    conv = jax.lax.dynamic_update_index_in_dim(
-                        conv, slots[0].astype(conv.dtype), at[0], 0)
-                    h = jax.lax.dynamic_update_index_in_dim(
-                        h, slots[1], at[0], 0)
-                with jax.named_scope(layers.ATTENTION), \
-                        jax.named_scope(layers.KV_CACHE):
-                    k = jax.lax.dynamic_update_index_in_dim(
-                        k, slots[2], at[1], 0)
-                    v = jax.lax.dynamic_update_index_in_dim(
-                        v, slots[3], at[1], 0)
-                stacks = (conv, h, k, v)
-            return (x, stacks), None
+                new = []
+                for j, (c, s) in enumerate(zip(caches, slots)):
+                    with _cache_scope(kinds[j // 2]):
+                        new.append(jax.lax.dynamic_update_index_in_dim(
+                            c, s.astype(c.dtype), at[j // 2], 0))
+                caches = tuple(new)
+            return (x, caches), None
 
-        # each step's slots: its own, and for the other kind the last seen
-        at, last = [], {"mamba": 0, "kv": 0}
-        for kind, i in steps:
-            last["mamba" if kind == "mamba" else "kv"] = i
-            at.append((last["mamba"], last["kv"]))
-        sched = (jnp.asarray([kinds.index(k) for k, _ in steps]),
-                 jnp.asarray([i for _, i in steps]), jnp.asarray(at))
-        stacks = (tuple(cache[n] for n in ("conv", "h", "k", "v"))
-                  if cached else None)
+        # each step's slots: its own, and for the other kinds the last used
+        at, last = [], dict.fromkeys(kinds, 0)
+        for step in steps:
+            last[step.kind] = step.slot
+            at.append([last[kind] for kind in kinds])
+        sched = (jnp.asarray([stacks.index((s.kind, s.stack)) for s in steps]),
+                 jnp.asarray([s.index for s in steps]), jnp.asarray(at),
+                 jnp.asarray([s.is_global for s in steps]))
+        caches = tuple(cache[n] for n in names) if cached else None
         layer = layers.maybe_remat(layer, cfg.remat_policy)
-        (x, stacks), _ = self._scan(layer, (x, stacks), sched)
+        (x, caches), _ = self._scan(layer, (x, caches), sched)
         if cached:
-            cache = {**cache, **dict(zip(("conv", "h", "k", "v"), stacks))}
+            cache = {**cache, **dict(zip(names, caches))}
         return x, cache
 
-    def _hybrid_decode(self, params, cache, x, positions):
-        """One decode step through a hybrid stack, unrolled: each layer is
-        one call of a jitted function traced once per kind, which reads
-        and writes its own slot of the stacked cache at an index the
-        compiler sees as a constant, in place.  No layer's state is sliced
-        out as a scan's xs or stacked again as its ys."""
-        mamba = jax.jit(self._mamba_decode_layer)
-        attn = jax.jit(self._attn_decode_layer)
-        conv, h, k, v = (cache[n] for n in ("conv", "h", "k", "v"))
-        for kind, i in self._hybrid_schedule():
-            blk = self._hybrid_block(params, kind, i)
-            if kind == "mamba":
-                x, conv, h = mamba(blk, x, conv, h, jnp.int32(i))
-            else:
-                x, k, v = attn(blk, x, positions, k, v, cache["pos"],
-                               jnp.int32(i))
-        return x, {**cache, "conv": conv, "h": h, "k": k, "v": v}
+    def forward(self, params: Params, batch: dict) -> jax.Array:
+        cfg = self.cfg
+        x = self.embed_inputs(params, batch)
+        B, T, _ = x.shape
+        positions = jnp.arange(T)[None, :].repeat(B, 0)
+        x, _ = self._walk(params, x, positions, media=batch.get("media"))
+        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        n = batch["tokens"].shape[1]
+        if n != T:
+            x = x[:, T - n:]                    # strip conditioning frames
+        return self._unembed(params, x)
 
     # ---------------- loss ----------------
 
@@ -541,230 +527,87 @@ class Model:
                 media: jax.Array | None = None,
                 mask: jax.Array | None = None) -> tuple[jax.Array, dict]:
         """Fill the decode cache from a (B, T) prompt; returns last-position
-        logits and the cache positioned at T.  ``mask`` (B, T), false at
-        padding: the hybrids' mamba layers leave their state unchanged
-        there (attention attends to it, as in the other families)."""
+        logits and the cache positioned after the prompt (and any
+        conditioning frames).  ``mask`` (B, T), false at padding: the mamba
+        layers leave their state unchanged there (attention attends to
+        it)."""
         cfg = self.cfg
-        T = tokens.shape[1]
         batch = {"tokens": tokens}
         if media is not None:
             batch["media"] = media
         x = self.embed_inputs(params, batch)
-        B = x.shape[0]
-        positions = jnp.arange(x.shape[1])[None, :].repeat(B, 0)
-        flags = self._layer_is_global()
-
-        if cfg.family in ("dense", "moe", "audio"):
-            if "moe_blocks" in params:
-                x, cache = self._moe_grouped_pass(params, cache, x,
-                                                  positions, 0)
-            else:
-                def layer(x, inp):
-                    blk, is_global, kc, vc = inp
-                    x, (nk, nv) = self._decoder_layer(
-                        blk, x, positions, is_global, kv_cache=(kc, vc),
-                        cache_len=0)
-                    return x, (nk, nv)
-
-                layer = layers.maybe_remat(layer, cfg.remat_policy)
-                x, (nk, nv) = self._scan(
-                    layer, x,
-                    (params["blocks"], flags, cache["k"], cache["v"]))
-                cache = {**cache, "k": nk, "v": nv}
-        elif cfg.family == "vlm":
-            # fill media K/V once, then run the decode-group path over T
-            cross = params["cross_blocks"]
-            mtok = jnp.einsum("bmd,dk->bmk", media.astype(x.dtype),
-                              params["media_proj"])
-            mk = jnp.einsum("bmd,gdhk->gbmhk", mtok, cross["attn"]["wk"])
-            mv = jnp.einsum("bmd,gdhk->gbmhk", mtok, cross["attn"]["wv"])
-            cache = {**cache, "media_k": mk.astype(cache["media_k"].dtype),
-                     "media_v": mv.astype(cache["media_v"].dtype)}
-            x, cache = self._decode_vlm(params, cache, x, positions, media)
-        elif cfg.family == "ssm":
-            def layer(x, inp):
-                blk, conv, h = inp
-                x, (nc, nh) = self._ssm_layer(blk, x, state=(conv, h))
-                return x, (nc, nh)
-
-            layer = layers.maybe_remat(layer, cfg.remat_policy)
-            x, (nc, nh) = self._scan(
-                layer, x, (params["blocks"], cache["conv"], cache["h"]))
-            cache = {**cache, "conv": nc, "h": nh}
-        elif cfg.family == "hybrid":
-            x, cache = self._hybrid_scan(params, cache, x, positions, mask)
-
+        B, T, _ = x.shape
+        positions = jnp.arange(T)[None, :].repeat(B, 0)
+        x, cache = self._walk(params, x, positions, cache, media, mask)
         x = layers.rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
-        cache = {**cache, "pos": jnp.asarray(
-            T + (cfg.n_media_tokens if cfg.family == "audio" else 0),
-            jnp.int32)}
+        cache = {**cache, "pos": jnp.asarray(T, jnp.int32)}
         return logits, cache
 
     # ---------------- decode ----------------
 
     def init_cache(self, batch_size: int, max_len: int) -> dict:
+        """Each kind's stacked cache, a slot per step of that kind: K/V for
+        attention, media K/V for cross steps, the conv window and SSM state
+        for mamba layers."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        L, K, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        K, Dh = cfg.n_kv_heads, cfg.head_dim
+        n = {kind: 0 for kind in _CACHE_SLOTS}
+        for step in self._schedule():
+            n[step.kind] += 1
         cache: dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
-        if cfg.family in ("dense", "moe", "audio", "vlm"):
-            cache["k"] = jnp.zeros((L, batch_size, max_len, K, Dh), dtype)
-            cache["v"] = jnp.zeros((L, batch_size, max_len, K, Dh), dtype)
-        if cfg.family == "vlm":
-            n_cross = cfg.n_layers // cfg.cross_attn_every
-            cache["media_k"] = jnp.zeros(
-                (n_cross, batch_size, cfg.n_media_tokens, K, Dh), dtype)
-            cache["media_v"] = jnp.zeros_like(cache["media_k"])
-        if cfg.family in ("ssm", "hybrid"):
-            # conv window and SSM state for the mamba layers only, K/V for
-            # the attention layers only
-            kinds = ([k for k, _ in self._hybrid_schedule()]
-                     if cfg.family == "hybrid" else ["mamba"] * L)
-            n_ssm = kinds.count("mamba")
-            n_attn = len(kinds) - n_ssm
-            di, n = cfg.d_inner, cfg.ssm_state
+        if n["mamba"]:
+            di, N = cfg.d_inner, cfg.ssm_state
             cache["conv"] = jnp.zeros(
-                (n_ssm, batch_size, cfg.ssm_conv - 1, ssm.conv_width(cfg)),
-                dtype)
+                (n["mamba"], batch_size, cfg.ssm_conv - 1,
+                 ssm.conv_width(cfg)), dtype)
             if cfg.mamba_version == 1:
-                cache["h"] = jnp.zeros((n_ssm, batch_size, di, n),
-                                       jnp.float32)
+                state = (di, N)
             else:
-                H = di // cfg.ssm_head_dim
-                cache["h"] = jnp.zeros(
-                    (n_ssm, batch_size, H, cfg.ssm_head_dim, n), jnp.float32)
-            if n_attn:
-                cache["k"] = jnp.zeros(
-                    (n_attn, batch_size, max_len, K, Dh), dtype)
-                cache["v"] = jnp.zeros_like(cache["k"])
+                state = (di // cfg.ssm_head_dim, cfg.ssm_head_dim, N)
+            cache["h"] = jnp.zeros((n["mamba"], batch_size, *state),
+                                   jnp.float32)
+        if n["attention"]:
+            cache["k"] = jnp.zeros(
+                (n["attention"], batch_size, max_len, K, Dh), dtype)
+            cache["v"] = jnp.zeros_like(cache["k"])
+        if n["cross"]:
+            cache["media_k"] = jnp.zeros(
+                (n["cross"], batch_size, cfg.n_media_tokens, K, Dh), dtype)
+            cache["media_v"] = jnp.zeros_like(cache["media_k"])
         return cache
 
     def decode_step(self, params: Params, cache: dict, tokens: jax.Array,
                     media: jax.Array | None = None
                     ) -> tuple[jax.Array, dict]:
-        """One serve step: tokens (B, 1) -> logits (B, 1, V), updated cache."""
+        """One serve step: tokens (B, 1) -> logits (B, 1, V), updated cache.
+
+        Unrolled over the schedule: each step is one call of its kind's
+        jitted layer, traced once per kind and weight layout, which keeps
+        tracing and lowering at a layer's cost.  A scan would slice every
+        layer's cache out as xs and stack the new ones as ys."""
         cfg = self.cfg
         with jax.named_scope(layers.EMBED):
             x = self._scale_embedding(params["embed"][tokens])
         pos = cache["pos"]
         B = tokens.shape[0]
         positions = jnp.full((B, 1), pos, jnp.int32)
-        flags = self._layer_is_global()
-
-        if cfg.family in ("dense", "moe", "audio"):
-            if "moe_blocks" in params:
-                x, cache = self._moe_grouped_pass(params, cache, x,
-                                                  positions, pos)
-            else:
-                # unrolled: each layer writes its token into the stacked
-                # cache and reads its own slice at an index the compiler
-                # sees as a constant, both in place, where a scan would
-                # slice every layer's cache out as xs and stack the new
-                # ones as ys.  One jitted layer, traced once and called
-                # per layer, keeps tracing and lowering at one layer's cost
-                layer = jax.jit(self._decoder_layer)
-                kv = (cache["k"], cache["v"])
-                for i in range(cfg.n_layers):
-                    x, kv = layer(_take(params["blocks"], i), x, positions,
-                                  flags[i], kv_cache=kv, cache_len=pos,
-                                  layer=jnp.int32(i))
-                cache = {**cache, "k": kv[0], "v": kv[1]}
-        elif cfg.family == "vlm":
-            x, cache = self._decode_vlm(params, cache, x, positions, media)
-        elif cfg.family == "ssm":
-            def layer(x, inp):
-                blk, conv, h = inp
-                x, (nc, nh) = self._ssm_layer(blk, x, state=(conv, h))
-                return x, (nc, nh)
-
-            x, (nc, nh) = self._scan(
-                layer, x, (params["blocks"], cache["conv"], cache["h"]))
-            cache = {**cache, "conv": nc, "h": nh}
-        elif cfg.family == "hybrid":
-            x, cache = self._hybrid_decode(params, cache, x, positions)
-
+        run = {"attention": jax.jit(self._attn_decode_layer),
+               "mamba": jax.jit(self._mamba_decode_layer),
+               "cross": jax.jit(self._cross_decode_layer)}
+        caches = {n: a for n, a in cache.items() if n != "pos"}
+        for step in self._schedule():
+            names = _CACHE_SLOTS[step.kind]
+            x, slots = run[step.kind](
+                self._weights(params, step.stack, step.index), x,
+                tuple(caches[n] for n in names), jnp.int32(step.slot),
+                positions, pos, step.is_global)
+            caches.update(zip(names, slots))
         x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
-        cache = {**cache, "pos": pos + 1}
+        cache = {**cache, **caches, "pos": pos + 1}
         return logits, cache
-
-    def _moe_grouped_pass(self, params, cache, x, positions, pos):
-        """Cached pass for moe_every>1 (llama4): cache rows are laid out as
-        [dense layers in scan order, then moe layers]."""
-        cfg = self.cfg
-        k = cfg.moe_every - 1
-        n_groups = cfg.n_layers // cfg.moe_every
-        n_dense = n_groups * k
-        dense = jax.tree.map(
-            lambda a: a.reshape(n_groups, k, *a.shape[1:]), params["blocks"])
-        kd = cache["k"][:n_dense].reshape(n_groups, k, *cache["k"].shape[1:])
-        vd = cache["v"][:n_dense].reshape(n_groups, k, *cache["v"].shape[1:])
-        km, vm = cache["k"][n_dense:], cache["v"][n_dense:]
-
-        def group(x, inp):
-            dgrp, mblk, kc, vc, kmc, vmc = inp
-
-            def inner(x, st):
-                blk, kcc, vcc = st
-                x, (nk, nv) = self._decoder_layer(
-                    blk, x, positions, True, kv_cache=(kcc, vcc),
-                    cache_len=pos)
-                return x, (nk, nv)
-
-            x, (nkd, nvd) = self._scan(inner, x, (dgrp, kc, vc))
-            x, (nkm, nvm) = self._decoder_layer(
-                mblk, x, positions, True, kv_cache=(kmc, vmc), cache_len=pos)
-            return x, (nkd, nvd, nkm, nvm)
-
-        x, (nkd, nvd, nkm, nvm) = self._scan(
-            group, x, (dense, params["moe_blocks"], kd, vd, km, vm))
-        cache = {**cache,
-                 "k": jnp.concatenate(
-                     [nkd.reshape(n_dense, *nkd.shape[2:]), nkm]),
-                 "v": jnp.concatenate(
-                     [nvd.reshape(n_dense, *nvd.shape[2:]), nvm])}
-        return x, cache
-
-    def _decode_vlm(self, params, cache, x, positions, media):
-        cfg = self.cfg
-        k = cfg.cross_attn_every
-        n_groups = cfg.n_layers // k
-        pos = cache["pos"]
-        blocks = jax.tree.map(
-            lambda a: a.reshape(n_groups, k, *a.shape[1:]), params["blocks"])
-        flags = self._layer_is_global().reshape(n_groups, k)
-        kr = cache["k"].reshape(n_groups, k, *cache["k"].shape[1:])
-        vr = cache["v"].reshape(n_groups, k, *cache["v"].shape[1:])
-
-        def group(x, inp):
-            grp, cross, fl, kc, vc, mk, mv = inp
-
-            def self_layer(x, inner):
-                blk, g, kcc, vcc = inner
-                x, (nk, nv) = self._decoder_layer(
-                    blk, x, positions, g, kv_cache=(kcc, vcc), cache_len=pos)
-                return x, (nk, nv)
-
-            x, (nk, nv) = self._scan(self_layer, x, (grp, fl, kc, vc))
-            h = layers.rms_norm(x, cross["ln"], cfg.norm_eps)
-            # cross-attn against the cached media K/V (computed at prefill)
-            with jax.named_scope(layers.ATTENTION):
-                spec = self._attn_spec()
-                q = jnp.einsum("btd,dhk->bthk", h, cross["attn"]["wq"])
-                out = layers.attention(q, mk, mv, spec,
-                                       q_offset=mk.shape[1], is_global=True)
-                a = jnp.einsum("bthk,hkd->btd", out, cross["attn"]["wo"])
-            x = x + jnp.tanh(cross["gate"]).astype(x.dtype) * a
-            return x, (nk, nv)
-
-        x, (nk, nv) = self._scan(
-            group, x, (blocks, params["cross_blocks"], flags, kr, vr,
-                       cache["media_k"], cache["media_v"]))
-        cache = {**cache,
-                 "k": nk.reshape(cfg.n_layers, *nk.shape[2:]),
-                 "v": nv.reshape(cfg.n_layers, *nv.shape[2:])}
-        return x, cache
 
 
 def build(cfg: ModelConfig) -> Model:

@@ -99,7 +99,7 @@ def test_decode_matches_forward(arch, key, seed):
 def test_local_global_flags_gemma():
     cfg = registry.get("gemma2-9b")
     m = model_lib.build(cfg)
-    flags = np.asarray(m._layer_is_global())
+    flags = np.asarray([step.is_global for step in m._schedule()])
     assert flags.shape == (42,)
     assert flags[1::2].all() and not flags[0::2].any()
 
