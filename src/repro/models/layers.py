@@ -4,7 +4,8 @@ Everything here is config-driven and shape-polymorphic so one implementation
 serves all ten assigned architectures: RMSNorm, RoPE, GQA attention with an
 online-softmax KV-block scan (causal, sliding-window, logit softcap — no
 O(T^2) mask materialization) or, for prefill and training on a TPU, a fused
-Pallas flash kernel, and (Sw/Ge)GLU MLPs.
+Pallas flash kernel, one token's attention over its cache (on a TPU a Pallas
+kernel that reads only the blocks up to its position), and (Sw/Ge)GLU MLPs.
 """
 
 from __future__ import annotations
@@ -219,6 +220,48 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, 1, H, Dh).astype(q.dtype)
 
 
+def decode_block(spec: AttnSpec, batch: int, max_len: int, dtype) -> int:
+    """The cache block that one token's attention reads at a time on a TPU
+    (``kernels.ops.paged_decode_attention``), or 0 where
+    :func:`decode_attention` reads the whole cache: a mesh of several
+    devices, or shapes the kernel does not take."""
+    from repro.kernels import ops
+    if not _one_device():
+        return 0
+    return ops.decode_block(batch, max_len, spec.n_kv_heads,
+                            spec.n_heads // spec.n_kv_heads, spec.head_dim,
+                            jnp.dtype(dtype).itemsize)
+
+
+def _decode(q, ck, cv, spec: AttnSpec, *, layer, pos, is_global):
+    """One new token's attention over the cache (B, S, K, Dh), or over the
+    stack of every layer's (L, B, S, K, Dh) at ``layer``.  Where
+    :func:`decode_block` gives a block, the program lowered for a TPU
+    reads only the blocks up to ``pos``'s, through the Pallas decode
+    kernel; elsewhere, and on every other platform, the whole cache
+    through :func:`decode_attention`."""
+    if layer is None:
+        ck, cv, layer = ck[None], cv[None], 0
+
+    def whole(q, ck, cv, layer, pos, is_global):
+        return decode_attention(q, ck[layer], cv[layer], spec, pos=pos,
+                                is_global=is_global)
+
+    args = (q, ck, cv, jnp.asarray(layer), jnp.asarray(pos),
+            jnp.asarray(is_global))
+    if not decode_block(spec, q.shape[0], ck.shape[2], ck.dtype):
+        return whole(*args)
+    from repro.kernels import ops
+
+    def kernel(q, ck, cv, layer, pos, is_global):
+        # lowered only for a TPU: never interpreted
+        return ops.paged_decode_attention(
+            q, ck, cv, layer, pos, scale=spec.scale or q.shape[-1] ** -0.5,
+            window=spec.window, softcap=spec.softcap, is_global=is_global,
+            interpret=False)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=whole)
+
+
 def init_attn_params(key, d_model: int, spec: AttnSpec, dtype,
                      qk_norm: bool = False) -> Params:
     ks = jax.random.split(key, 4)
@@ -254,8 +297,9 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
     * decode: ``kv_cache`` holds (B, S, K, Dh), or the stack of every
       layer's, (L, B, S, K, Dh), of which this is ``layer``; x is the new
       token(s); the cache is updated at ``cache_len`` and returned whole.
-      One new token reads it through :func:`decode_attention`; a prompt
-      at a ``cache_len`` of a Python 0 attends to its own K/V alone.
+      One new token reads it through :func:`_decode` (on a TPU only the
+      blocks up to its position); a prompt at a ``cache_len`` of a Python
+      0 attends to its own K/V alone.
     * cross-attention: ``xkv`` supplies the key/value source sequence.
     """
     src = x if xkv is None else xkv
@@ -282,16 +326,16 @@ def attn_block(params: Params, x: jax.Array, spec: AttnSpec, *,
         with jax.named_scope(KV_CACHE):
             ck = jax.lax.dynamic_update_slice(ck, kn, (*at, 0, pos, 0, 0))
             cv = jax.lax.dynamic_update_slice(cv, vn, (*at, 0, pos, 0, 0))
-        lk, lv = (ck, cv) if layer is None else (ck[layer], cv[layer])
         if x.shape[1] == 1:
-            out = decode_attention(q, lk, lv, spec, pos=pos,
-                                   is_global=is_global)
+            out = _decode(q, ck, cv, spec, layer=layer, pos=pos,
+                          is_global=is_global)
         elif isinstance(pos, int) and pos == 0:
             # a prompt from position 0 sees only its own keys: attend to
             # the fresh K/V, not to the cache's empty positions
             out = attention(q, k.astype(ck.dtype), v.astype(cv.dtype), spec,
                             is_global=is_global)
         else:
+            lk, lv = (ck, cv) if layer is None else (ck[layer], cv[layer])
             out = attention(q, lk, lv, spec, q_offset=pos,
                             is_global=is_global, kv_len=pos + x.shape[1])
         k_all, v_all = ck, cv

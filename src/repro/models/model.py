@@ -578,6 +578,16 @@ class Model:
             cache["media_v"] = jnp.zeros_like(cache["media_k"])
         return cache
 
+    def decode_kv_block(self, batch: int, max_len: int) -> int | None:
+        """The block in which a decode step's attention reads the K/V
+        cache when lowered for a TPU (``layers.decode_block``), 0 where it
+        reads all ``max_len`` positions, None for a stack with no
+        attention."""
+        if all(step.kind != "attention" for step in self._schedule()):
+            return None
+        return layers.decode_block(self._attn_spec(), batch, max_len,
+                                   self.cfg.dtype)
+
     def decode_step(self, params: Params, cache: dict, tokens: jax.Array,
                     media: jax.Array | None = None
                     ) -> tuple[jax.Array, dict]:

@@ -15,7 +15,11 @@ calls; the per-iteration ones also ``step=<k>``.  The counters in
 ``Engine.metrics.snapshot()["counters"]`` are ``engine.host_reads``
 (device-to-host reads), ``engine.decode_steps`` (``decode_step`` calls) and
 ``engine.decode_steps_kept`` (calls whose sampled token some request
-appended).  The gauges ``engine.cache_bytes.kv`` and
+appended), and per decode step ``engine.decode_kv_positions_held`` (the
+cache's ``max_len`` positions) and ``engine.decode_kv_positions_read``
+(what one layer's attention reads of them: on a TPU, where the decode
+kernel takes the shapes, the live positions rounded up to its block, else
+all of them).  The gauges ``engine.cache_bytes.kv`` and
 ``engine.cache_bytes.state`` record, as each batch's cache is built, the
 bytes it holds for attention keys and values and for recurrent state (a
 mamba layer's conv window and SSM state).
@@ -73,6 +77,9 @@ class Engine:
         reads = self.metrics.counter("engine.host_reads")
         steps = self.metrics.counter("engine.decode_steps")
         kept = self.metrics.counter("engine.decode_steps_kept")
+        held = self.metrics.counter("engine.decode_kv_positions_held")
+        read = self.metrics.counter("engine.decode_kv_positions_read")
+        blk = self._decode_block(B)
         with jax.profiler.TraceAnnotation("engine.generate", batch=n):
             with jax.profiler.TraceAnnotation("engine.admit", batch=n):
                 plen = max(len(p) for p in prompts)
@@ -124,7 +131,17 @@ class Engine:
                     cur = self._sample(logits, key)
                     pos += 1
                 steps.inc()
+                if blk is not None:
+                    # the step's token sits at pos - 1: pos positions live
+                    held.inc(cfg.max_len)
+                    read.inc(-(-pos // blk) * blk if blk else cfg.max_len)
         return out
+
+    def _decode_block(self, batch: int) -> int | None:
+        """The block in which this platform's decode step reads the K/V
+        cache, 0 where it reads all of it, None where it holds none."""
+        blk = self.model.decode_kv_block(batch, self.cfg.max_len)
+        return 0 if blk and jax.default_backend() != "tpu" else blk
 
     def _record_cache(self, cache: dict) -> None:
         now = time.perf_counter_ns()

@@ -187,12 +187,12 @@ def _instructions(compiled) -> str:
 
 
 # sha256 of ``_instructions`` of granite-3-2b's programs, with jax 0.9.0 and
-# its TPU compiler: ``decode_step`` as compiled before the layer-pattern
-# hybrid (granite-4.0-h) came into the model code, ``prefill`` since it
-# attends through the causal flash kernel
+# its TPU compiler: ``decode_step`` since it reads the cache through the
+# decode kernel, ``prefill`` since it attends through the causal flash
+# kernel
 DENSE_PROGRAMS = {
-    "decode_step": "37d14987a704186952f85bc8c74a159d"
-                   "71d08cd7f9467d1913275b1b771b1ff5",
+    "decode_step": "52d2397c2b466d0be839e7197f5fb0a4"
+                   "374b740463e19f7bd4913f2ab5fcd1fc",
     "prefill": "e7970615d54c606e0795ead77385553c"
                "abfd2bc0c7221cdef59eeed037fe7ee9",
 }
@@ -324,8 +324,8 @@ def _kernel_calls(compiled) -> dict[tuple[str, str], int]:
     def calls(comp: str) -> collections.Counter:
         out = collections.Counter()
         for line in comps[comp]:
-            kernel = re.match(r"\s*%(splash_\w+?)(?:\.\d+)? = .*"
-                              r"custom-call\(", line)
+            kernel = re.match(r"\s*%(splash_\w+?|paged_decode_attention)"
+                              r"(?:\.\d+)? = .*custom-call\(", line)
             if kernel:
                 op = re.search(r'op_name="([^"]*)"', line)
                 out[kernel.group(1), layer_trace.kind_of(op.group(1))] += 1
@@ -346,6 +346,42 @@ def test_prefill_runs_the_flash_kernel_per_layer(serving):
     no other kernel."""
     assert _kernel_calls(serving["prefill"]) == {
         ("splash_mqa_fwd_no_residuals", "attention"): 40}
+
+
+def test_decode_step_reads_the_cache_through_the_decode_kernel(
+        serve_decode):
+    """At serve-decode's shapes each layer's one-token attention is one
+    call of the Pallas decode kernel, under the ``attention`` scope, which
+    reads the stacked cache where it lies (the in-place test above finds
+    no copy or slice of a layer's cache)."""
+    assert _kernel_calls(serve_decode[0]) == {
+        ("paged_decode_attention", "attention"): 40}
+
+
+def test_hybrid_decode_fits_and_runs_the_decode_kernel(hybrid_decode):
+    """granite-4.0-h-micro's decode at serve-chat-b32's 32 x 2304 fits one
+    chip, its 4 attention layers each reading through the kernel."""
+    _fits(hybrid_decode[0])
+    assert _kernel_calls(hybrid_decode[0]) == {
+        ("paged_decode_attention", "attention"): 4}
+
+
+def test_wide_head_decode_fits_and_runs_the_decode_kernel(one_chip):
+    """glm4-9b-20L's decode at serve-prefill's cache of 8192 positions:
+    heads of 128 (2 KV heads, 16 query heads each), which the kernel
+    reads position-major, as the chip keeps them, in one call per
+    layer."""
+    model = model_lib.build(registry.get("glm4-9b").with_depth(20))
+    params = _placed(jax.eval_shape(model.init, jax.random.key(0)),
+                     one_chip)
+    engine = Engine(model, params, ServeConfig(max_batch=1, max_len=8192))
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(1, 8192)),
+                    one_chip)
+    tokens = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    compiled = engine.decode.lower(params, cache, tokens, None).compile()
+    _fits(compiled)
+    assert _kernel_calls(compiled) == {
+        ("paged_decode_attention", "attention"): 20}
 
 
 def test_long_prompt_prefill_fits(one_chip):
@@ -402,13 +438,14 @@ def test_train_step_runs_the_flash_kernel_both_ways(train_step):
 
 
 # sha256 of ``_instructions`` of the other pinned programs, with jax 0.9.0
-# and its TPU compiler, as compiled before one schedule walked every
-# family's layer stack: granite-4.0-h-micro's decode step at the
-# serve-chat-b32 shapes and its prefill at ``hybrid_prefill``'s, and
-# granite-3-2b's 2-layer train step
+# and its TPU compiler: granite-4.0-h-micro's decode step at the
+# serve-chat-b32 shapes since it reads its K/V through the decode kernel,
+# and, as compiled before one schedule walked every family's layer stack,
+# its prefill at ``hybrid_prefill``'s shapes and granite-3-2b's 2-layer
+# train step
 PINNED_PROGRAMS = {
-    "hybrid_decode": "2236280cf099ef5af91fedf445b9f576"
-                     "36a306cba8852319006ae007e5077d0e",
+    "hybrid_decode": "48dc711130b39f759f1d9fc9eb32c917"
+                     "3d9e1bf60339098a7079694860c8e9c8",
     "hybrid_prefill": "7d1dddcf4be2c94cf941e15457b221ea"
                       "0f11524b34b8498c183e97a1f2a42d14",
     "train_step": "28729543994ab0f09c7bf5bd60787207"

@@ -147,6 +147,40 @@ def test_engine_counters(batch, max_new):
         assert c["engine.decode_steps_kept"] == calls * (max_new - 1)
 
 
+@pytest.mark.parametrize("batch,max_new", [(1, 2), (4, 5)])
+def test_engine_counts_decode_kv_positions(batch, max_new):
+    """Per decode step, the cache's ``max_len`` positions held, and as many
+    read: off the TPU attention reads the whole cache."""
+    cfg, eng = _no_eos_engine()
+    prompts = [[2 + i, 3, 4] for i in range(batch)]
+    for calls in (1, 2):
+        eng.generate(prompts, max_new=max_new)
+        c = eng.metrics.snapshot()["counters"]
+        assert c["engine.decode_kv_positions_held"] == (
+            calls * max_new * eng.cfg.max_len)
+        assert (c["engine.decode_kv_positions_read"]
+                == c["engine.decode_kv_positions_held"])
+
+
+@pytest.mark.parametrize("arch,blk", [("granite-3-2b", 32),
+                                      ("granite-4.0-h-micro", 8),
+                                      ("falcon-mamba-7b", None)])
+def test_engine_counts_the_blocks_a_kernel_reads(arch, blk):
+    """Where the decode kernel reads the cache in blocks, each step counts
+    its live positions (the prompt, then one more per step) rounded up to
+    a block; a stack with no attention counts nothing."""
+    cfg, eng = _engine(arch, eos_token=-1)
+    eng._decode_block = lambda batch: blk
+    prompt, max_new = [2, 3, 4, 5, 6], 40
+    eng.generate([prompt] * 2, max_new=max_new)
+    c = eng.metrics.snapshot()["counters"]
+    lives = range(len(prompt) + 1, len(prompt) + max_new + 1)
+    want = sum(-(-n // blk) * blk for n in lives) if blk else 0
+    assert c["engine.decode_kv_positions_read"] == want
+    assert c["engine.decode_kv_positions_held"] == (
+        max_new * eng.cfg.max_len if blk else 0)
+
+
 @pytest.mark.parametrize("arch,kv,state", [
     ("granite-3-2b", 2 * 2 * 4 * 96 * 2 * 16 * 2, 0),
     # 2 attention layers; 2 mamba layers' conv windows (3 x 144 bf16) and
